@@ -50,6 +50,9 @@ def main() -> None:
                     help="write results to this JSON artifact path")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_checkpoint, bench_comm,
                             bench_hierarchical, bench_hypergeometric,
                             bench_kernels, bench_llm,

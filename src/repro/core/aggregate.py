@@ -25,6 +25,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# Eq 1 is a weighted average and stays f32, as on the CPU. The TPU's
+# default matmul precision rounds f32 operands to bf16, which would
+# round every aggregated parameter to 8 mantissa bits each round.
+EQ1_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def weighted_average(stacked_updates: Any, weights: jax.Array,
                      literal_eq1: bool = False,
@@ -73,7 +78,8 @@ def multi_weighted_average(stacked_updates: Any, weights: jax.Array,
         return jax.tree_util.tree_unflatten(treedef, outs)
 
     def avg(leaf: jax.Array) -> jax.Array:
-        acc = jnp.einsum("b...,mb->m...", leaf.astype(jnp.float32), w)
+        acc = jnp.einsum("b...,mb->m...", leaf.astype(jnp.float32), w,
+                         precision=EQ1_PRECISION)
         df = denoms.reshape((-1,) + (1,) * (acc.ndim - 1))
         return (acc / df).astype(leaf.dtype)
 

@@ -26,15 +26,16 @@ Three generations of round data plane live here (DESIGN.md §2):
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.core.aggregate import multi_weighted_average
+from repro.core.aggregate import EQ1_PRECISION, multi_weighted_average
 
 
 def make_local_train(loss_fn: Callable, lr: float, batch_size: int
@@ -131,6 +132,22 @@ def _pair_train(loss_fn: Callable, lr: float) -> Callable:
     return one_pair
 
 
+# Work pairs trained side by side in one step of the sequential map over
+# a work batch. Peak round memory then follows this constant and the
+# model, not the bucket B (which reaches 240 at the paper's regime).
+PAIR_GROUP = 8
+
+
+def _train_pairs(one_pair: Callable, stacked, m_idx, xs, ys, d_idx, perms):
+    """Train every (model, device) pair of a work batch: ``PAIR_GROUP``
+    pairs vmapped at a time, the groups in a sequential ``lax.map``.
+    Each pair's computation is ``one_pair`` exactly as under a full
+    ``vmap``; only how many run at once is bounded."""
+    return jax.lax.map(
+        lambda a: one_pair(stacked, a[0], xs, ys, a[1], a[2]),
+        (m_idx, d_idx, perms), batch_size=PAIR_GROUP)
+
+
 def make_pair_train(loss_fn: Callable, lr: float) -> Callable:
     """The TRAIN phase alone: jitted fn(stacked_params, model_idx (B,),
     xs (N,n,...), ys (N,n), device_idx (B,), perms (B,T,b)) -> trained
@@ -140,8 +157,7 @@ def make_pair_train(loss_fn: Callable, lr: float) -> Callable:
     lets the pipelined executors dispatch round t+1's training
     speculatively while round t's eval matrices are still in flight and
     simply discard the result on a mispeculation (DESIGN.md §10)."""
-    return jax.jit(jax.vmap(_pair_train(loss_fn, lr),
-                            in_axes=(None, 0, None, None, 0, 0)))
+    return jax.jit(functools.partial(_train_pairs, _pair_train(loss_fn, lr)))
 
 
 def make_group_train(loss_fn: Callable, lr: float, batch_size: int
@@ -204,10 +220,14 @@ def _scatter_rows(stacked, agg, agg_rows, keep=None):
 
 
 def _eval_gathered(eval_model, stacked, idx, xs, ys):
-    """Step 5: gather the scheduled bank rows and score each on every
-    device's split — the (rows, N) accuracy matrix."""
-    rows = jax.tree.map(lambda a: a[idx], stacked)
-    return jax.vmap(eval_model, in_axes=(0, None, None))(rows, xs, ys)
+    """Step 5: score each scheduled bank row on every device's split —
+    the (rows, N) accuracy matrix. Rows run one after another in a
+    ``lax.map``, so peak memory is one row's eval over all N splits
+    whatever the number of rows."""
+    def one_row(i):
+        return eval_model(jax.tree.map(lambda a: a[i], stacked), xs, ys)
+
+    return jax.lax.map(one_row, idx)
 
 
 def make_fused_round(loss_fn: Callable, acc_fn: Callable, lr: float,
@@ -256,8 +276,8 @@ def make_fused_round(loss_fn: Callable, acc_fn: Callable, lr: float,
 
     def round_step(stacked, m_idx, d_idx, perms, w, agg_rows,
                    live_idx, test_idx, xs, ys, vx, vy, tx, ty):
-        trained = jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        trained = _train_pairs(one_pair, stacked, m_idx, xs, ys,
+                               d_idx, perms)
         agg = _aggregate_rows(trained, w, quantize_bits, use_agg_kernel)
         new_stacked = _scatter_rows(stacked, agg, agg_rows)
         val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
@@ -430,8 +450,8 @@ def make_sharded_round(loss_fn: Callable, acc_fn: Callable, lr: float,
 
     def body(stacked, m_idx, d_idx, perms, w, agg_rows, agg_keep,
              live_idx, test_idx, xs, ys, vx, vy, tx, ty):
-        trained = jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        trained = _train_pairs(one_pair, stacked, m_idx, xs, ys,
+                               d_idx, perms)
         agg = _aggregate_rows(trained, w, quantize_bits, use_agg_kernel)
         new_stacked = _scatter_rows(stacked, agg, agg_rows, keep=agg_keep)
         val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
@@ -442,7 +462,7 @@ def make_sharded_round(loss_fn: Callable, acc_fn: Callable, lr: float,
         body, mesh=mesh,
         in_specs=(row, row, row, row, row, row, row, row, row,
                   rep, rep, rep, rep, rep, rep),
-        out_specs=(row, row, row), check_rep=False)
+        out_specs=(row, row, row), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -462,7 +482,7 @@ def make_sharded_eval(acc_fn: Callable, mesh: jax.sharding.Mesh
 
     return jax.jit(shard_map(mat, mesh=mesh,
                              in_specs=(row, row, rep, rep),
-                             out_specs=row, check_rep=False))
+                             out_specs=row, check_vma=False))
 
 
 def _make_sharded_pair_train(loss_fn: Callable, lr: float,
@@ -478,12 +498,12 @@ def _make_sharded_pair_train(loss_fn: Callable, lr: float,
     rep = P()
 
     def body(stacked, m_idx, d_idx, perms, xs, ys):
-        return jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        return _train_pairs(one_pair, stacked, m_idx, xs, ys, d_idx,
+                            perms)
 
     return jax.jit(shard_map(body, mesh=mesh,
                              in_specs=(bank_spec, row, row, row, rep, rep),
-                             out_specs=row, check_rep=False))
+                             out_specs=row, check_vma=False))
 
 
 def make_sharded_train(loss_fn: Callable, lr: float,
@@ -514,7 +534,7 @@ def make_sharded_apply(mesh: jax.sharding.Mesh, quantize_bits: int = 0,
 
     step = shard_map(body, mesh=mesh,
                      in_specs=(row, row, row, row, row),
-                     out_specs=row, check_rep=False)
+                     out_specs=row, check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -545,7 +565,7 @@ def make_sharded_finish(acc_fn: Callable, mesh: jax.sharding.Mesh,
     step = shard_map(body, mesh=mesh,
                      in_specs=(row, row, row, row, row, row, row,
                                rep, rep, rep, rep),
-                     out_specs=(row, row, row), check_rep=False)
+                     out_specs=(row, row, row), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -568,7 +588,7 @@ def make_sharded_pair_eval(acc_fn: Callable, mesh: jax.sharding.Mesh
 
     return jax.jit(shard_map(body, mesh=mesh,
                              in_specs=(row, row, row, rep, rep),
-                             out_specs=row, check_rep=False))
+                             out_specs=row, check_vma=False))
 
 
 def shard_eval_pairs(pair_rows: "list[int]", pair_device: "list[int]",
@@ -679,7 +699,8 @@ def _aggregate_rows_psum(trained, w, quantize_bits: int, axis: str):
     reduction-order float drift otherwise (the 2-D equivalence tier
     pins discrete state exactly and params to reduction order)."""
     num = jax.tree.map(
-        lambda t: jnp.einsum("b...,ab->a...", t.astype(jnp.float32), w),
+        lambda t: jnp.einsum("b...,ab->a...", t.astype(jnp.float32), w,
+                             precision=EQ1_PRECISION),
         trained)
     num = jax.lax.psum(num, axis)
     den = jnp.maximum(jax.lax.psum(jnp.sum(w, axis=1), axis), 1e-12)
@@ -727,8 +748,8 @@ def make_sharded2d_round(loss_fn: Callable, acc_fn: Callable, lr: float,
 
     def body(stacked, m_idx, d_idx, perms, w, agg_rows, agg_keep,
              live_idx, test_idx, xs, ys, vx, vy, tx, ty):
-        trained = jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        trained = _train_pairs(one_pair, stacked, m_idx, xs, ys,
+                               d_idx, perms)
         agg = _aggregate_rows_psum(trained, w, quantize_bits, "data")
         new_stacked = _scatter_rows(stacked, agg, agg_rows, keep=agg_keep)
         val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
@@ -739,7 +760,7 @@ def make_sharded2d_round(loss_fn: Callable, acc_fn: Callable, lr: float,
         body, mesh=mesh,
         in_specs=(row, cell, cell, cell, grid, row, row, row, row,
                   drow, drow, drow, drow, drow, drow),
-        out_specs=(row, grid, grid), check_rep=False)
+        out_specs=(row, grid, grid), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -755,13 +776,13 @@ def make_sharded2d_train(loss_fn: Callable, lr: float,
     cell = P(("model", "data"))
 
     def body(stacked, m_idx, d_idx, perms, xs, ys):
-        return jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        return _train_pairs(one_pair, stacked, m_idx, xs, ys, d_idx,
+                            perms)
 
     return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(row, cell, cell, cell, drow, drow),
-        out_specs=cell, check_rep=False))
+        out_specs=cell, check_vma=False))
 
 
 def make_sharded2d_apply(mesh: jax.sharding.Mesh, quantize_bits: int = 0
@@ -779,7 +800,7 @@ def make_sharded2d_apply(mesh: jax.sharding.Mesh, quantize_bits: int = 0
 
     step = shard_map(body, mesh=mesh,
                      in_specs=(row, cell, grid, row, row),
-                     out_specs=row, check_rep=False)
+                     out_specs=row, check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -808,7 +829,7 @@ def make_sharded2d_finish(acc_fn: Callable, mesh: jax.sharding.Mesh,
         body, mesh=mesh,
         in_specs=(row, cell, grid, row, row, row, row,
                   drow, drow, drow, drow),
-        out_specs=(row, grid, grid), check_rep=False)
+        out_specs=(row, grid, grid), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -827,7 +848,7 @@ def make_sharded2d_eval(acc_fn: Callable, mesh: jax.sharding.Mesh
 
     return jax.jit(shard_map(mat, mesh=mesh,
                              in_specs=(row, row, drow, drow),
-                             out_specs=grid, check_rep=False))
+                             out_specs=grid, check_vma=False))
 
 
 def make_sharded2d_pair_eval(acc_fn: Callable, mesh: jax.sharding.Mesh
@@ -849,7 +870,7 @@ def make_sharded2d_pair_eval(acc_fn: Callable, mesh: jax.sharding.Mesh
 
     return jax.jit(shard_map(body, mesh=mesh,
                              in_specs=(row, cell, cell, drow, drow),
-                             out_specs=cell, check_rep=False))
+                             out_specs=cell, check_vma=False))
 
 
 def make_sharded_fedavg_round(loss_fn: Callable, acc_fn: Callable,
@@ -873,10 +894,11 @@ def make_sharded_fedavg_round(loss_fn: Callable, acc_fn: Callable,
     rep = P()
 
     def body(stacked, m_idx, d_idx, perms, w, xs, ys, vx, vy, tx, ty):
-        trained = jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        trained = _train_pairs(one_pair, stacked, m_idx, xs, ys,
+                               d_idx, perms)
         num = jax.tree.map(
-            lambda t: jnp.einsum("b...,b->...", t.astype(jnp.float32), w),
+            lambda t: jnp.einsum("b...,b->...", t.astype(jnp.float32), w,
+                                 precision=EQ1_PRECISION),
             trained)
         num = jax.lax.psum(num, "model")
         den = jnp.maximum(jax.lax.psum(jnp.sum(w), "model"), 1e-12)
@@ -890,7 +912,7 @@ def make_sharded_fedavg_round(loss_fn: Callable, acc_fn: Callable,
     step = shard_map(
         body, mesh=mesh,
         in_specs=(rep, row, row, row, row, rep, rep, rep, rep, rep, rep),
-        out_specs=(rep, rep, rep), check_rep=False)
+        out_specs=(rep, rep, rep), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -916,7 +938,8 @@ def make_sharded_fedavg_finish(acc_fn: Callable,
 
     def body(stacked, trained, w, vx, vy, tx, ty):
         num = jax.tree.map(
-            lambda t: jnp.einsum("b...,b->...", t.astype(jnp.float32), w),
+            lambda t: jnp.einsum("b...,b->...", t.astype(jnp.float32), w,
+                                 precision=EQ1_PRECISION),
             trained)
         num = jax.lax.psum(num, "model")
         den = jnp.maximum(jax.lax.psum(jnp.sum(w), "model"), 1e-12)
@@ -929,7 +952,7 @@ def make_sharded_fedavg_finish(acc_fn: Callable,
 
     step = shard_map(body, mesh=mesh,
                      in_specs=(rep, row, row, rep, rep, rep, rep),
-                     out_specs=(rep, rep, rep), check_rep=False)
+                     out_specs=(rep, rep, rep), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -938,7 +961,8 @@ def _fedavg_psum_avg(stacked, trained, w, axes):
     ``psum`` over ``axes`` completes the average, leaving the global
     model replicated everywhere."""
     num = jax.tree.map(
-        lambda t: jnp.einsum("b...,b->...", t.astype(jnp.float32), w),
+        lambda t: jnp.einsum("b...,b->...", t.astype(jnp.float32), w,
+                             precision=EQ1_PRECISION),
         trained)
     num = jax.lax.psum(num, axes)
     den = jnp.maximum(jax.lax.psum(jnp.sum(w), axes), 1e-12)
@@ -972,8 +996,8 @@ def make_sharded2d_fedavg_round(loss_fn: Callable, acc_fn: Callable,
     vcol = P(None, "data")
 
     def body(stacked, m_idx, d_idx, perms, w, xs, ys, vx, vy, tx, ty):
-        trained = jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        trained = _train_pairs(one_pair, stacked, m_idx, xs, ys,
+                               d_idx, perms)
         new_stacked = _fedavg_psum_avg(stacked, trained, w,
                                        ("model", "data"))
         model = jax.tree.map(lambda a: a[0], new_stacked)
@@ -985,7 +1009,7 @@ def make_sharded2d_fedavg_round(loss_fn: Callable, acc_fn: Callable,
         body, mesh=mesh,
         in_specs=(rep, cell, cell, cell, cell,
                   drow, drow, drow, drow, drow, drow),
-        out_specs=(rep, vcol, vcol), check_rep=False)
+        out_specs=(rep, vcol, vcol), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -1001,13 +1025,13 @@ def make_sharded2d_fedavg_train(loss_fn: Callable, lr: float,
     rep = P()
 
     def body(stacked, m_idx, d_idx, perms, xs, ys):
-        return jax.vmap(one_pair, in_axes=(None, 0, None, None, 0, 0))(
-            stacked, m_idx, xs, ys, d_idx, perms)
+        return _train_pairs(one_pair, stacked, m_idx, xs, ys, d_idx,
+                            perms)
 
     return jax.jit(shard_map(
         body, mesh=mesh,
         in_specs=(rep, cell, cell, cell, drow, drow),
-        out_specs=cell, check_rep=False))
+        out_specs=cell, check_vma=False))
 
 
 def make_sharded2d_fedavg_finish(acc_fn: Callable,
@@ -1032,7 +1056,7 @@ def make_sharded2d_fedavg_finish(acc_fn: Callable,
 
     step = shard_map(body, mesh=mesh,
                      in_specs=(rep, cell, cell, drow, drow, drow, drow),
-                     out_specs=(rep, vcol, vcol), check_rep=False)
+                     out_specs=(rep, vcol, vcol), check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -1052,7 +1076,7 @@ def make_sharded2d_fedavg_eval(acc_fn: Callable,
 
     return jax.jit(shard_map(body, mesh=mesh,
                              in_specs=(rep, drow, drow),
-                             out_specs=vcol, check_rep=False))
+                             out_specs=vcol, check_vma=False))
 
 
 def make_perms(rng: np.random.Generator, n_devices: int, n_examples: int,

@@ -1,14 +1,17 @@
 """Pallas TPU kernel: blockwise symmetric int8 quantize / dequantize.
 
-Tiling: the (R, D) payload is processed in VMEM tiles of
-``(TILE_R, TILE_D)`` = (256, 512) — 512 f32 = 2 KiB per lane-row, tile =
-512 KiB in fp32, comfortably inside the ~16 MiB v5e VMEM alongside the
-int8 output tile and the (TILE_R, TILE_D // block) scale tile. The scale
-block size (128) matches the TPU lane width so the per-block max reduces
-along lanes without cross-lane shuffles.
+Layout: the payload arrives as one quantization block per row, an
+``(n_blocks, block)`` array (``ops`` reshapes any leaf into it). A row's
+scale is then a per-row max along lanes, and the scales form an
+``(n_blocks, 1)`` column whose block ``(TILE_ROWS, 1)`` spans the full
+last dimension — the form the TPU compiler accepts for a lane dimension
+that is not a multiple of 128.
 
-Grid: (R / TILE_R, D / TILE_D); each program owns its tile exclusively —
-no cross-tile reductions, so the kernel scales linearly with payload.
+Grid: ``(n_blocks / TILE_ROWS,)``; each program owns ``TILE_ROWS`` whole
+blocks, so no reduction crosses a program. A (512, 128) f32 tile is
+256 KiB, well inside the v5e's scoped VMEM with the int8 output tile
+and the scale column. ``TILE_ROWS`` is a multiple of 32, the sublane
+tiling of int8 arrays.
 """
 from __future__ import annotations
 
@@ -19,70 +22,60 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_R = 256
-TILE_D = 512
-BLOCK = 128
+TILE_ROWS = 512
 
 
-def _quantize_kernel(x_ref, q_ref, s_ref, *, qmax: float, block: int):
-    x = x_ref[...].astype(jnp.float32)                    # (tr, td)
-    tr, td = x.shape
-    nb = td // block
-    xb = x.reshape(tr, nb, block)
-    s = jnp.max(jnp.abs(xb), axis=2) / qmax               # (tr, nb)
+def _quantize_kernel(x_ref, q_ref, s_ref, *, qmax: float):
+    x = x_ref[...].astype(jnp.float32)                    # (tile, block)
+    s = jnp.max(jnp.abs(x), axis=1, keepdims=True) / qmax  # (tile, 1)
     s = jnp.maximum(s, 1e-12)
-    q = jnp.clip(jnp.round(xb / s[:, :, None]), -qmax, qmax)
-    q_ref[...] = q.reshape(tr, td).astype(jnp.int8)
-    s_ref[...] = s.astype(jnp.float32)
+    q_ref[...] = jnp.clip(jnp.round(x / s), -qmax, qmax).astype(jnp.int8)
+    s_ref[...] = s
 
 
-def _dequantize_kernel(q_ref, s_ref, x_ref, *, block: int):
-    q = q_ref[...].astype(jnp.float32)
-    tr, td = q.shape
-    nb = td // block
-    x = q.reshape(tr, nb, block) * s_ref[...][:, :, None]
-    x_ref[...] = x.reshape(tr, td).astype(x_ref.dtype)
+def _dequantize_kernel(q_ref, s_ref, x_ref):
+    x = q_ref[...].astype(jnp.float32) * s_ref[...]
+    x_ref[...] = x.astype(x_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "block", "interpret"))
-def quantize_2d(x: jax.Array, bits: int = 8, block: int = BLOCK,
-                interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """x (R, D) with R % TILE_R == 0, D % TILE_D == 0 (callers pad).
+@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
+def quantize_2d(x: jax.Array, bits: int = 8,
+                *, interpret: bool) -> Tuple[jax.Array, jax.Array]:
+    """x (n_blocks, block), one quantization block per row, with
+    ``n_blocks % TILE_ROWS == 0`` (callers pad).
 
-    Returns (q (R, D) int8, scales (R, D // block) f32).
-    """
-    R, D = x.shape
+    Returns (q (n_blocks, block) int8, scales (n_blocks, 1) f32)."""
+    n, block = x.shape
     qmax = float((1 << (bits - 1)) - 1)
-    grid = (R // TILE_R, D // TILE_D)
     return pl.pallas_call(
-        functools.partial(_quantize_kernel, qmax=qmax, block=block),
-        grid=grid,
-        in_specs=[pl.BlockSpec((TILE_R, TILE_D), lambda i, j: (i, j))],
+        functools.partial(_quantize_kernel, qmax=qmax),
+        grid=(n // TILE_ROWS,),
+        in_specs=[pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((TILE_R, TILE_D), lambda i, j: (i, j)),
-            pl.BlockSpec((TILE_R, TILE_D // block), lambda i, j: (i, j)),
+            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_ROWS, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((R, D), jnp.int8),
-            jax.ShapeDtypeStruct((R, D // block), jnp.float32),
+            jax.ShapeDtypeStruct((n, block), jnp.int8),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "dtype", "interpret"))
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
 def dequantize_2d(q: jax.Array, scales: jax.Array, dtype=jnp.float32,
-                  block: int = BLOCK, interpret: bool = True) -> jax.Array:
-    R, D = q.shape
-    grid = (R // TILE_R, D // TILE_D)
+                  *, interpret: bool) -> jax.Array:
+    """q (n_blocks, block) int8, scales (n_blocks, 1) -> (n_blocks, block)."""
+    n, block = q.shape
     return pl.pallas_call(
-        functools.partial(_dequantize_kernel, block=block),
-        grid=grid,
+        _dequantize_kernel,
+        grid=(n // TILE_ROWS,),
         in_specs=[
-            pl.BlockSpec((TILE_R, TILE_D), lambda i, j: (i, j)),
-            pl.BlockSpec((TILE_R, TILE_D // block), lambda i, j: (i, j)),
+            pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_ROWS, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((TILE_R, TILE_D), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, D), dtype),
+        out_specs=pl.BlockSpec((TILE_ROWS, block), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, block), dtype),
         interpret=interpret,
     )(q, scales)

@@ -1,14 +1,14 @@
-"""Public wrappers for the weighted-aggregation kernels (pytree leaves)."""
+"""Public wrappers for the weighted-aggregation kernels (pytree leaves).
+
+The kernel body runs in interpret mode on the CPU and compiled on the
+TPU (``repro.kernels.interpret_mode``)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.weighted_agg import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def weighted_agg(stacked_leaf: jax.Array, weights: jax.Array,
@@ -21,7 +21,7 @@ def weighted_agg(stacked_leaf: jax.Array, weights: jax.Array,
     pad = (-D) % K.TILE_D
     flat = jnp.pad(flat, ((0, 0), (0, pad)))
     out = K.weighted_agg_2d(flat, weights, jnp.asarray(denom),
-                            interpret=not _on_tpu())
+                            interpret=interpret_mode())
     return out[:D].reshape(tail).astype(stacked_leaf.dtype)
 
 
@@ -37,18 +37,19 @@ def multi_weighted_agg(stacked_leaf: jax.Array, weights: jax.Array,
     pad = (-D) % K.TILE_D
     flat = jnp.pad(flat, ((0, 0), (0, pad)))
     out = K.multi_weighted_agg_2d(flat, weights, jnp.asarray(denoms),
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
     return out[:, :D].reshape((M,) + tail).astype(stacked_leaf.dtype)
 
 
 def dequant_agg(q: jax.Array, scales: jax.Array, weights: jax.Array,
                 denom: jax.Array, block: int = 128) -> jax.Array:
-    """Aggregate compressed payloads directly. q (N, D), D % block == 0."""
+    """Aggregate compressed payloads directly. q (N, D), D % block == 0,
+    scales (N, D // block)."""
     N, D = q.shape
-    pad = (-D) % K.TILE_D
-    qp = jnp.pad(q, ((0, 0), (0, pad)))
-    sp = jnp.pad(scales, ((0, 0), (0, (qp.shape[1] // block)
-                                   - scales.shape[1])))
-    out = K.dequant_agg_2d(qp, sp, weights, jnp.asarray(denom),
-                           block=block, interpret=not _on_tpu())
-    return out[:D]
+    nb = D // block
+    pad = (-nb) % K.TILE_BLOCKS
+    qb = jnp.pad(q.reshape(N, nb, block), ((0, 0), (0, pad), (0, 0)))
+    sb = jnp.pad(scales.reshape(N, nb, 1), ((0, 0), (0, pad), (0, 0)))
+    out = K.dequant_agg_2d(qb, sb, weights, jnp.asarray(denom),
+                           interpret=interpret_mode())
+    return out[:nb].reshape(D)

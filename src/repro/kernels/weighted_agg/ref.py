@@ -13,7 +13,8 @@ import jax.numpy as jnp
 def weighted_agg_ref(updates: jax.Array, weights: jax.Array,
                      denom: jax.Array) -> jax.Array:
     acc = jnp.einsum("nd,n->d", updates.astype(jnp.float32),
-                     weights.astype(jnp.float32))
+                     weights.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     return acc / denom
 
 
@@ -26,7 +27,8 @@ def multi_weighted_agg_ref(updates: jax.Array, weights: jax.Array,
     different model or is padding); denoms (M,) -> out (M, D).
     """
     acc = jnp.einsum("bd,mb->md", updates.astype(jnp.float32),
-                     weights.astype(jnp.float32))
+                     weights.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     return acc / denoms[:, None]
 
 

@@ -150,10 +150,6 @@ def run_case(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         t_compile = time.time() - t0
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # pre-0.5 jax: one dict per program, with nesting observed to
-        # vary ([dict] vs [[dict]]) — unwrap until the dict
-        while isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         # loop-aware accounting from the optimized HLO (cost_analysis
         # counts while bodies once — see roofline/hlo_analyzer.py)
         acc = hlo_analyze(compiled.as_text())

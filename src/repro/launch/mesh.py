@@ -13,13 +13,8 @@ import jax
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]
                ) -> jax.sharding.Mesh:
-    # axis_types / AxisType only exist on newer jax; Auto is the default
-    # behaviour there, so omitting the kwarg is equivalent on older jax.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

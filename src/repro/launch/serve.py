@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_prefill_step, make_serve_step
 from repro.models import transformer as tf
 from repro.serve.draft import draft_config, truncate_lm_params
@@ -98,6 +99,7 @@ def main() -> None:
     ap.add_argument("--draft-layers", type=int, default=1,
                     help="draft depth for --spec-k (truncated target)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -31,6 +31,7 @@ from repro.config import FedCDConfig
 from repro.configs import get_arch, reduced
 from repro.core.spec import EngineSpec
 from repro.federated.llm import FedLLMTrainer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -58,6 +59,7 @@ def main() -> None:
                     help="resume from a checkpoint directory (or a "
                          "ckpts root — picks the latest valid step)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:
