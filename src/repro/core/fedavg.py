@@ -184,7 +184,7 @@ class FedAvgServer:
         return restore_server_state(self, resolved)
 
     def run_round(self, t: int) -> FedAvgRound:
-        t0 = time.time()
+        t0 = time.perf_counter_ns()
         cfg = self.cfg
         if self._prefetch is not None and self._prefetch[0] == t:
             participating, perms = self._prefetch[1]
@@ -212,7 +212,7 @@ class FedAvgServer:
         m = FedAvgRound(
             round=t, test_acc=result.test_acc, val_acc=result.val_acc,
             comm_bytes=2 * int(participating.sum()) * self._model_bytes,
-            wall_s=time.time() - t0)
+            wall_s=(time.perf_counter_ns() - t0) * 1e-9)
         self.metrics.append(m)
         self._fault(t, "post-readback")
         if self._ckpt is not None:

@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import spans
 from repro.checkpoint.state import (CheckpointManager, latest_checkpoint,
                                     restore_server_state,
                                     save_server_state)
@@ -82,7 +83,7 @@ class RoundMetrics:
     live_models: int                # models alive on the server
     score_std: float                # mean over devices of σ(c_i) (Fig 9)
     comm_bytes: int                 # up+down transport this round (§3.6)
-    wall_s: float
+    wall_s: float                   # monotonic, round start to collect
     preferred: np.ndarray           # (N,) argmax-score model id (Fig 7)
 
 
@@ -342,10 +343,28 @@ class FedCDServer:
 
     # -- Algorithm 1 -------------------------------------------------------
     def run_round(self, t: int) -> RoundMetrics:
-        t0 = time.time()
+        # wall_s is the root span: both read these two monotonic stamps
+        # (the checkpoint, which saves wall_s, follows the round)
+        t0 = time.perf_counter_ns()
+        spans.round_scope(t)
+        with spans.span("fedcd.round", start_ns=t0) as root:
+            metrics = self._run_round(t)
+            t1 = time.perf_counter_ns()
+            root.end_ns = t1
+        metrics.wall_s = (t1 - t0) * 1e-9
+        self.metrics.append(metrics)
+        self._fault(t, "post-readback")
+        if self._ckpt is not None:
+            with spans.span("fedcd.checkpoint"):
+                self._ckpt.maybe_save(self, t)
+        return metrics
+
+    def _run_round(self, t: int) -> RoundMetrics:
         cfg = self.cfg
-        joined, left = self._apply_churn(t)
-        sample = self._round_sample(t)
+        with spans.span("fedcd.churn"):
+            joined, left = self._apply_churn(t)
+        with spans.span("fedcd.sample"):
+            sample = self._round_sample(t)
         c = normalized_scores(self.state)
 
         churn_next = (self.scenario is not None
@@ -358,7 +377,9 @@ class FedCDServer:
         self.executor.launch(plan)
         # overlap: draw round t+1's participation + perms while the
         # dispatched work is still executing (ROADMAP: async sampling)
-        self._prefetch = (t + 1, self._draw_sample(self._present_after(t + 1)))
+        with spans.span("fedcd.sample"):
+            self._prefetch = (t + 1,
+                              self._draw_sample(self._present_after(t + 1)))
         if self.pipeline:
             # cross-round speculation: enqueue round t+1's training from
             # the prefetched sample + pre-lifecycle state (DESIGN.md §10)
@@ -369,26 +390,25 @@ class FedCDServer:
         result = self.executor.readback()
 
         transfers = plan.transfers
-        self.state = push_accuracies(self.state, result.accs)
-        self.state, _ = apply_deletions(self.state, self.registry, t, cfg)
-        if t in cfg.milestones:
-            self.state, cloned = clone_at_milestone(
-                self.state, self.registry, t, cfg, self.life_rng,
-                clone_params_fn=self._maybe_compress)
-            transfers += sum(int(self.state.active[:, m2].sum())
-                             for m2 in self.registry.live_ids())
-            self.executor.on_clones(cloned)
-            self.planner.on_clones(cloned)   # clones inherit fold mass
+        with spans.span("fedcd.lifecycle"):
+            self.state = push_accuracies(self.state, result.accs)
+            self.state, _ = apply_deletions(self.state, self.registry, t,
+                                            cfg)
+            if t in cfg.milestones:
+                self.state, cloned = clone_at_milestone(
+                    self.state, self.registry, t, cfg, self.life_rng,
+                    clone_params_fn=self._maybe_compress)
+                transfers += sum(int(self.state.active[:, m2].sum())
+                                 for m2 in self.registry.live_ids())
+                self.executor.on_clones(cloned)
+                self.planner.on_clones(cloned)   # clones inherit fold mass
 
-        metrics = self._collect(t, transfers, time.time() - t0)
-        self.metrics.append(metrics)
-        self._fault(t, "post-readback")
-        if self._ckpt is not None:
-            self._ckpt.maybe_save(self, t)
-        return metrics
+        with spans.span("fedcd.collect"):
+            return self._collect(t, transfers)
 
     # -- metrics -----------------------------------------------------------
-    def _collect(self, t: int, transfers: int, wall: float) -> RoundMetrics:
+    def _collect(self, t: int, transfers: int) -> RoundMetrics:
+        """The round's metrics; ``run_round`` sets ``wall_s``."""
         c = normalized_scores(self.state)
         preferred = np.argmax(np.where(self.state.active, c, -1.0), axis=1)
         test_acc, val_acc = self.executor.collect(preferred)
@@ -402,7 +422,7 @@ class FedCDServer:
             live_models=len(self.registry.live_ids()),
             score_std=float(np.mean(stds)) if stds else 0.0,
             comm_bytes=self._transport_bytes(transfers),
-            wall_s=wall, preferred=preferred)
+            wall_s=0.0, preferred=preferred)
 
     def run(self, rounds: int, log_every: int = 0) -> List[RoundMetrics]:
         # a resumed server continues from the round after its checkpoint

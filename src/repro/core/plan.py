@@ -26,6 +26,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.config import FedCDConfig
 from repro.core.registry import ModelRegistry
 from repro.core.scores import ScoreState
@@ -395,28 +396,29 @@ class RoundPlanner:
         """``churn``: the (joined ids, left ids) applied at this round's
         start; ``churn_next``: whether round t+1 has scheduled device
         lifecycle events (consumed by the speculation guard)."""
-        participating, perms = sample
-        agg_models, pair_model, pair_device, transfers = gather_pairs(
-            state, registry, participating)
-        live = registry.live_ids()
-        joins, leaves = churn if churn is not None else ([], [])
-        plan = RoundPlan(
-            round=t, participating=participating, perms=perms,
-            scores=scores, live=live, agg_models=agg_models,
-            pair_model=pair_model, pair_device=pair_device,
-            transfers=transfers, val_stale=[], test_stale=[],
-            clone_milestone=t in self.cfg.milestones,
-            device_joins=list(joins), device_leaves=list(leaves),
-            churn_next=churn_next)
-        if self.semisync is not None:
-            # may replace scores with a weight-zeroed copy, shrink the
-            # agg set and attach folds — BEFORE eval staleness, which
-            # keys on the set of models whose params change
-            self.semisync.resolve(plan, live)
-        plan.val_stale, plan.test_stale = self._eval_sets(
-            state, live, set(plan.changed_models()), hints)
-        self._sparse_val(plan, state)
-        return plan
+        with spans.span("fedcd.plan"):
+            participating, perms = sample
+            agg_models, pair_model, pair_device, transfers = gather_pairs(
+                state, registry, participating)
+            live = registry.live_ids()
+            joins, leaves = churn if churn is not None else ([], [])
+            plan = RoundPlan(
+                round=t, participating=participating, perms=perms,
+                scores=scores, live=live, agg_models=agg_models,
+                pair_model=pair_model, pair_device=pair_device,
+                transfers=transfers, val_stale=[], test_stale=[],
+                clone_milestone=t in self.cfg.milestones,
+                device_joins=list(joins), device_leaves=list(leaves),
+                churn_next=churn_next)
+            if self.semisync is not None:
+                # may replace scores with a weight-zeroed copy, shrink the
+                # agg set and attach folds — BEFORE eval staleness, which
+                # keys on the set of models whose params change
+                self.semisync.resolve(plan, live)
+            plan.val_stale, plan.test_stale = self._eval_sets(
+                state, live, set(plan.changed_models()), hints)
+            self._sparse_val(plan, state)
+            return plan
 
     def build_speculative(self, t: int,
                           sample: Tuple[np.ndarray, np.ndarray],

@@ -33,12 +33,13 @@ why repair is exact (DESIGN.md §10).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.config import FedCDConfig
 from repro.core import quantize as qz
 from repro.core.aggregate import multi_weighted_average, weighted_average
@@ -84,6 +85,13 @@ class RoundResult:
     accs: np.ndarray                     # (N, M_cap) val accuracies
 
 
+class _Pending(NamedTuple):
+    """One dispatched eval matrix: the device output ``readback`` waits
+    on, and the reader that copies it into per-model host rows."""
+    fut: Any
+    read: Callable[[], Dict[int, np.ndarray]]
+
+
 @dataclass
 class PipelineStats:
     """Cross-round speculation accounting (reported by the benches)."""
@@ -117,6 +125,19 @@ class TrainMeta:
     b_pad: int
     pair_groups: Optional[List[List[int]]] = None    # sharded only
     positions: Optional[List[int]] = None
+
+
+def _count_pairs(n_pairs: int, n_slots: int) -> None:
+    """Count a bucketed work batch: its real pairs and its padding."""
+    spans.count("pairs", n_pairs)
+    spans.count("pairs_padded", n_slots - n_pairs)
+
+
+def _to_host(fut: Any) -> np.ndarray:
+    """Copy one eval output to the host, counting its bytes."""
+    mat = np.asarray(fut)
+    spans.count("d2h_bytes", mat.nbytes)
+    return mat
 
 
 def _group_positions(groups: List[List[int]], width: int,
@@ -384,8 +405,8 @@ class FusedExecutor(RoundExecutor):
         self._test_cache: Dict[int, np.ndarray] = {}
         self._pred_rows: List[int] = [0]
         self._needs_refresh = False
-        self._pending: Optional[Tuple[RoundPlan, Dict[str, Callable]]] = \
-            None
+        self._pending: Optional[
+            Tuple[RoundPlan, Dict[str, _Pending]]] = None
         self._spec: Optional[Tuple[RoundPlan, Any, TrainMeta,
                                    Tuple[int, int]]] = None
         self._spec_graveyard: List[Any] = []
@@ -489,16 +510,17 @@ class FusedExecutor(RoundExecutor):
         repaired speculation they are a superset of the plan's pairs —
         dead pairs score c=0 and models outside the plan's agg set get
         no weight row, so the superset aggregates identically)."""
-        agg_rows = pad_live_rows(plan.agg_models, self._row_floor)
-        slot = {m: j for j, m in enumerate(plan.agg_models)}
-        w = np.zeros((len(agg_rows), meta.b_pad), np.float32)
-        for k, (m, d) in enumerate(zip(meta.pair_model,
-                                       meta.pair_device)):
-            j = slot.get(m)
-            if j is not None:
-                w[j, k] = plan.scores[d, m]
-        w[len(plan.agg_models):] = w[0]
-        return w, agg_rows
+        with spans.span("fedcd.launch.args"):
+            agg_rows = pad_live_rows(plan.agg_models, self._row_floor)
+            slot = {m: j for j, m in enumerate(plan.agg_models)}
+            w = np.zeros((len(agg_rows), meta.b_pad), np.float32)
+            for k, (m, d) in enumerate(zip(meta.pair_model,
+                                           meta.pair_device)):
+                j = slot.get(m)
+                if j is not None:
+                    w[j, k] = plan.scores[d, m]
+            w[len(plan.agg_models):] = w[0]
+            return w, agg_rows
 
     def _batch_args(self, pair_model: List[int],
                     pair_device: List[int], perms: np.ndarray
@@ -509,11 +531,14 @@ class FusedExecutor(RoundExecutor):
         programs can never see different batch schedules. ``d_idx``
         entries are data-bank ROWS (resolved here, at dispatch); perms
         stay device-ID-indexed — they are host control-plane state."""
-        m_idx, d_idx, pperms = pad_work_batch(
-            pair_model, self._drows(pair_device),
-            [perms[d] for d in pair_device])
-        meta = TrainMeta(list(pair_model), list(pair_device), len(m_idx),
-                         positions=list(range(len(pair_model))))
+        with spans.span("fedcd.launch.args"):
+            m_idx, d_idx, pperms = pad_work_batch(
+                pair_model, self._drows(pair_device),
+                [perms[d] for d in pair_device])
+            meta = TrainMeta(list(pair_model), list(pair_device),
+                             len(m_idx),
+                             positions=list(range(len(pair_model))))
+        _count_pairs(len(pair_model), len(m_idx))
         return m_idx, d_idx, pperms, meta
 
     def _dispatch_train(self, tree: Any, pair_model: List[int],
@@ -521,26 +546,28 @@ class FusedExecutor(RoundExecutor):
                         ) -> Tuple[Any, TrainMeta]:
         m_idx, d_idx, pperms, meta = self._batch_args(pair_model,
                                                       pair_device, perms)
-        trained = self._train(tree, m_idx, *self._dev["train"], d_idx,
-                              pperms)
+        with spans.span("fedcd.launch.dispatch"):
+            trained = self._train(tree, m_idx, *self._dev["train"], d_idx,
+                                  pperms)
         return trained, meta
 
     def _dispatch_apply(self, trained: Any, meta: TrainMeta,
                         plan: RoundPlan) -> None:
         bank = self.registry.params
         w, agg_rows = self._apply_weights(meta, plan)
-        bank.swap(self._apply(bank.tree, trained, w, agg_rows))
+        with spans.span("fedcd.launch.dispatch"):
+            bank.swap(self._apply(bank.tree, trained, w, agg_rows))
 
     # -- eval dispatch / readers ------------------------------------------
-    def _val_reader_dense(self, fut: Any, models: List[int]) -> Callable:
+    def _val_reader_dense(self, fut: Any, models: List[int]) -> _Pending:
         def read() -> Dict[int, np.ndarray]:
-            mat = np.asarray(fut)[:len(models)]
+            mat = _to_host(fut)[:len(models)]
             return {m: self._to_id_row(mat[j])
                     for j, m in enumerate(models)}
-        return read
+        return _Pending(fut, read)
 
     def _val_reader_sparse(self, fut: Any, plan: RoundPlan,
-                           positions: List[int]) -> Callable:
+                           positions: List[int]) -> _Pending:
         """Merge sparse per-pair accuracies into full cached rows:
         pair k's value sits at ``positions[k]`` of the eval vector
         (identity for the single-device layout, shard-bucket slots for
@@ -548,7 +575,7 @@ class FusedExecutor(RoundExecutor):
         never-scored rows start at zero (only active entries are ever
         read — DESIGN.md §10)."""
         def read() -> Dict[int, np.ndarray]:
-            vec = np.asarray(fut)
+            vec = _to_host(fut)
             rows: Dict[int, np.ndarray] = {}
             for k, (m, d) in enumerate(zip(plan.val_pair_model,
                                            plan.val_pair_device)):
@@ -557,27 +584,30 @@ class FusedExecutor(RoundExecutor):
                         m, np.zeros(self.n_devices)).copy()
                 rows[m][d] = vec[positions[k]]
             return rows
-        return read
+        return _Pending(fut, read)
 
-    def _dispatch_sparse_val(self, plan: RoundPlan) -> Callable:
-        p = len(plan.val_pair_model)
-        p_pad = bucket_size(p)
-        m_idx = np.zeros(p_pad, np.int32)
-        m_idx[:p] = plan.val_pair_model
-        d_idx = np.zeros(p_pad, np.int32)
-        d_idx[:p] = self._drows(plan.val_pair_device)
-        fut = self._pair_eval(self.registry.params.tree, m_idx, d_idx,
-                              *self._dev["val"])
+    def _dispatch_sparse_val(self, plan: RoundPlan) -> _Pending:
+        with spans.span("fedcd.launch.args"):
+            p = len(plan.val_pair_model)
+            p_pad = bucket_size(p)
+            m_idx = np.zeros(p_pad, np.int32)
+            m_idx[:p] = plan.val_pair_model
+            d_idx = np.zeros(p_pad, np.int32)
+            d_idx[:p] = self._drows(plan.val_pair_device)
+        with spans.span("fedcd.launch.dispatch"):
+            fut = self._pair_eval(self.registry.params.tree, m_idx, d_idx,
+                                  *self._dev["val"])
         return self._val_reader_sparse(fut, plan, list(range(p)))
 
-    def _dispatch_dense(self, models: List[int], split: str) -> Callable:
-        fut = self._eval(self.registry.params.tree,
-                         pad_live_rows(models, self._row_floor),
-                         *self._dev[split])
+    def _dispatch_dense(self, models: List[int], split: str) -> _Pending:
+        rows = pad_live_rows(models, self._row_floor)
+        with spans.span("fedcd.launch.dispatch"):
+            fut = self._eval(self.registry.params.tree, rows,
+                             *self._dev[split])
         return self._val_reader_dense(fut, models)
 
-    def _dispatch_evals(self, plan: RoundPlan) -> Dict[str, Callable]:
-        pend: Dict[str, Callable] = {}
+    def _dispatch_evals(self, plan: RoundPlan) -> Dict[str, _Pending]:
+        pend: Dict[str, _Pending] = {}
         if plan.val_stale:
             pend["val"] = (self._dispatch_sparse_val(plan)
                            if plan.sparse_val
@@ -614,13 +644,16 @@ class FusedExecutor(RoundExecutor):
 
     # -- launch -----------------------------------------------------------
     def launch(self, plan: RoundPlan) -> None:
-        self._last_plan = plan
-        self._fold_stale(plan)
-        self._note_load(plan)
-        if self.pipeline:
-            self._launch_split(plan)
-        else:
-            self._launch_sync(plan)
+        with spans.span("fedcd.launch"):
+            self._last_plan = plan
+            self._fold_stale(plan)
+            self._note_load(plan)
+            spans.count("val_rows", len(plan.val_stale))
+            spans.count("test_rows", len(plan.test_stale))
+            if self.pipeline:
+                self._launch_split(plan)
+            else:
+                self._launch_sync(plan)
 
     def _note_load(self, plan: RoundPlan) -> None:
         pass                             # sharded executor observes load
@@ -633,14 +666,16 @@ class FusedExecutor(RoundExecutor):
             m_idx, d_idx, pperms, meta = self._batch_args(
                 plan.pair_model, plan.pair_device, plan.perms)
             w, agg_rows = self._apply_weights(meta, plan)
-            new_stacked, val_mat, test_mat = self._round(
-                bank.tree, m_idx, d_idx, pperms, w, agg_rows,
-                pad_live_rows(plan.val_stale or plan.live[:1]),
-                pad_live_rows(plan.test_stale or plan.live[:1]),
-                *self._dev["train"], *self._dev["val"],
-                *self._dev["test"])
+            with spans.span("fedcd.launch.args"):
+                val_rows = pad_live_rows(plan.val_stale or plan.live[:1])
+                test_rows = pad_live_rows(plan.test_stale or plan.live[:1])
+            with spans.span("fedcd.launch.dispatch"):
+                new_stacked, val_mat, test_mat = self._round(
+                    bank.tree, m_idx, d_idx, pperms, w, agg_rows, val_rows,
+                    test_rows, *self._dev["train"], *self._dev["val"],
+                    *self._dev["test"])
             bank.swap(new_stacked)
-            pend: Dict[str, Callable] = {}
+            pend: Dict[str, _Pending] = {}
             if plan.val_stale:
                 pend["val"] = self._val_reader_dense(val_mat,
                                                      plan.val_stale)
@@ -666,21 +701,23 @@ class FusedExecutor(RoundExecutor):
         self._pending = (plan, pend)
 
     def _finish_round(self, trained: Any, meta: TrainMeta,
-                      plan: RoundPlan) -> Dict[str, Callable]:
+                      plan: RoundPlan) -> Dict[str, _Pending]:
         """Aggregate + scatter + stale-row eval as ONE dispatch (the
         ``make_*_finish`` program) — everything the monolithic round
         does after training, with the same program fusion."""
         bank = self.registry.params
         w, agg_rows = self._apply_weights(meta, plan)
-        new_stacked, val_mat, test_mat = self._finish(
-            bank.tree, trained, w, agg_rows,
-            pad_live_rows(plan.val_stale or plan.live[:1],
-                          self._row_floor),
-            pad_live_rows(plan.test_stale or plan.live[:1],
-                          self._row_floor),
-            *self._dev["val"], *self._dev["test"])
+        with spans.span("fedcd.launch.args"):
+            val_rows = pad_live_rows(plan.val_stale or plan.live[:1],
+                                     self._row_floor)
+            test_rows = pad_live_rows(plan.test_stale or plan.live[:1],
+                                      self._row_floor)
+        with spans.span("fedcd.launch.dispatch"):
+            new_stacked, val_mat, test_mat = self._finish(
+                bank.tree, trained, w, agg_rows, val_rows, test_rows,
+                *self._dev["val"], *self._dev["test"])
         bank.swap(new_stacked)
-        pend: Dict[str, Callable] = {}
+        pend: Dict[str, _Pending] = {}
         if plan.val_stale:
             pend["val"] = self._val_reader_dense(val_mat, plan.val_stale)
         if plan.test_stale:
@@ -771,6 +808,10 @@ class FusedExecutor(RoundExecutor):
     def speculate(self, plan: RoundPlan) -> None:
         if not self.pipeline:
             return
+        with spans.span("fedcd.speculate"):
+            self._speculate(plan)
+
+    def _speculate(self, plan: RoundPlan) -> None:
         self._drop_speculation()
         if self._last_plan is not None and (
                 self._last_plan.clone_milestone
@@ -794,16 +835,33 @@ class FusedExecutor(RoundExecutor):
 
     # -- readback + collect -----------------------------------------------
     def readback(self) -> RoundResult:
-        plan, pend = self._pending
-        self._pending = None
+        with spans.span("fedcd.readback"):
+            plan, pend = self._pending
+            self._pending = None
+            with spans.span("fedcd.readback.wait"):
+                jax.block_until_ready([p.fut for p in pend.values()])
+            with spans.span("fedcd.readback.copy"):
+                accs = self._read_pending(plan, pend)
+            # the step's consumers have completed: retired bank trees and
+            # abandoned speculative batches can now destruct without
+            # blocking the host (registry docstring)
+            with spans.span("fedcd.readback.release"):
+                self.registry.params.release_retired()
+                self._spec_graveyard.clear()
+        return RoundResult(accs)
+
+    def _read_pending(self, plan: RoundPlan, pend: Dict[str, _Pending]
+                      ) -> np.ndarray:
+        """Copy the round's eval matrices into the row caches; the
+        (N, M_cap) validation accuracies of the live models."""
         if self._pending_harvest is not None:
             hplan, trained, meta = self._pending_harvest
             self._pending_harvest = None
             _harvest_rows(self._stale_updates, hplan, trained, meta)
         if "val" in pend:
-            self._val_cache.update(pend["val"]())
+            self._val_cache.update(pend["val"].read())
         if "test" in pend:
-            self._test_cache.update(pend["test"]())
+            self._test_cache.update(pend["test"].read())
         # a changed model's (aggregated or stale-folded) old test row is
         # stale: drop it unless it was just re-evaluated
         for m in plan.changed_models():
@@ -812,21 +870,18 @@ class FusedExecutor(RoundExecutor):
         accs = np.zeros((self.n_devices, self.cfg.max_models))
         for m in plan.live:
             accs[:, m] = self._val_cache[m]
-        # the step's consumers have completed: retired bank trees and
-        # abandoned speculative batches can now destruct without
-        # blocking the host (registry docstring)
-        self.registry.params.release_retired()
-        self._spec_graveyard.clear()
-        return RoundResult(accs)
+        return accs
 
     def eval_rows(self, rows: List[int], split: str) -> np.ndarray:
         """(len(rows), N) accuracy of the given models on one split —
         the standalone eval dispatch for collect's misprediction
         fallback and the quantized-cloning refresh."""
-        mat = np.asarray(self._eval(self.registry.params.tree,
-                                    pad_live_rows(rows, self._row_floor),
-                                    *self._dev[split]))
-        return np.stack([self._to_id_row(r) for r in mat[:len(rows)]])
+        with spans.span("fedcd.collect.eval"):
+            spans.count("eval_rows_extra", len(rows))
+            mat = np.asarray(self._eval(self.registry.params.tree,
+                                        pad_live_rows(rows, self._row_floor),
+                                        *self._dev[split]))
+            return np.stack([self._to_id_row(r) for r in mat[:len(rows)]])
 
     def _refresh_caches(self) -> None:
         """Quantized cloning made every clone's params differ from its
@@ -930,12 +985,14 @@ class ShardedExecutor(FusedExecutor):
 
     def _shard_row_slots(self, bank_rows: List[int]
                          ) -> Tuple[np.ndarray, Dict[int, int]]:
-        idx, groups, width = shard_rows(bank_rows, self._rows_per_shard,
-                                        self._n_shards,
-                                        minimum=self._row_floor)
-        pos = {r: s * width + j
-               for s, g in enumerate(groups) for j, r in enumerate(g)}
-        return idx, pos
+        with spans.span("fedcd.launch.args"):
+            idx, groups, width = shard_rows(bank_rows,
+                                            self._rows_per_shard,
+                                            self._n_shards,
+                                            minimum=self._row_floor)
+            pos = {r: s * width + j
+                   for s, g in enumerate(groups) for j, r in enumerate(g)}
+            return idx, pos
 
     def _batch_args(self, pair_model: List[int],
                     pair_device: List[int], perms: np.ndarray
@@ -943,14 +1000,16 @@ class ShardedExecutor(FusedExecutor):
                                TrainMeta]:
         # per-shard bucket floor scales down with the shard count: the
         # global work splits S ways (DESIGN.md §9)
-        m_idx, d_idx, pperms, pair_groups, b_pad = shard_work_batch(
-            self._rows(pair_model), self._drows(pair_device),
-            [perms[d] for d in pair_device], self._rows_per_shard,
-            self._n_shards, minimum=max(8 // self._n_shards, 2))
-        meta = TrainMeta(list(pair_model), list(pair_device), b_pad,
-                         pair_groups,
-                         positions=_group_positions(pair_groups, b_pad,
-                                                    len(pair_model)))
+        with spans.span("fedcd.launch.args"):
+            m_idx, d_idx, pperms, pair_groups, b_pad = shard_work_batch(
+                self._rows(pair_model), self._drows(pair_device),
+                [perms[d] for d in pair_device], self._rows_per_shard,
+                self._n_shards, minimum=max(8 // self._n_shards, 2))
+            meta = TrainMeta(list(pair_model), list(pair_device), b_pad,
+                             pair_groups,
+                             positions=_group_positions(pair_groups, b_pad,
+                                                        len(pair_model)))
+        _count_pairs(len(pair_model), len(m_idx))
         return m_idx, d_idx, pperms, meta
 
     def _dispatch_train(self, tree: Any, pair_model: List[int],
@@ -958,8 +1017,9 @@ class ShardedExecutor(FusedExecutor):
                         ) -> Tuple[Any, TrainMeta]:
         m_idx, d_idx, pperms, meta = self._batch_args(pair_model,
                                                       pair_device, perms)
-        trained = self._train(tree, m_idx, d_idx, pperms,
-                              *self._dev["train"])
+        with spans.span("fedcd.launch.dispatch"):
+            trained = self._train(tree, m_idx, d_idx, pperms,
+                                  *self._dev["train"])
         return trained, meta
 
     def _shard_agg_plan(self, agg_rows: List[int], meta: TrainMeta,
@@ -970,35 +1030,37 @@ class ShardedExecutor(FusedExecutor):
         guarding the scatter. Pairs are addressed by META's bucket
         columns; a repaired speculation's dead pairs simply find no
         slot (their model left the agg set) or score c=0."""
-        S = self._n_shards
-        row_of = self.registry.params.row_of
-        agg_idx, agg_groups, a_pad = shard_rows(
-            agg_rows, self._rows_per_shard, S, minimum=self._row_floor)
-        keep = np.zeros(S * a_pad, bool)
-        w = np.zeros((S * a_pad, meta.b_pad), np.float32)
-        for s, group in enumerate(agg_groups):
-            if not group:
-                continue
-            base = s * a_pad
-            keep[base:base + a_pad] = True
-            slot = {r: j for j, r in enumerate(group)}
-            for col, k in enumerate(meta.pair_groups[s]):
-                m, d = meta.pair_model[k], meta.pair_device[k]
-                j = slot.get(row_of[m])
-                if j is not None:
-                    w[base + j, col] = c[d, m]
-            w[base + len(group):base + a_pad] = w[base]
-        return agg_idx, keep, w
+        with spans.span("fedcd.launch.args"):
+            S = self._n_shards
+            row_of = self.registry.params.row_of
+            agg_idx, agg_groups, a_pad = shard_rows(
+                agg_rows, self._rows_per_shard, S, minimum=self._row_floor)
+            keep = np.zeros(S * a_pad, bool)
+            w = np.zeros((S * a_pad, meta.b_pad), np.float32)
+            for s, group in enumerate(agg_groups):
+                if not group:
+                    continue
+                base = s * a_pad
+                keep[base:base + a_pad] = True
+                slot = {r: j for j, r in enumerate(group)}
+                for col, k in enumerate(meta.pair_groups[s]):
+                    m, d = meta.pair_model[k], meta.pair_device[k]
+                    j = slot.get(row_of[m])
+                    if j is not None:
+                        w[base + j, col] = c[d, m]
+                w[base + len(group):base + a_pad] = w[base]
+            return agg_idx, keep, w
 
     def _dispatch_apply(self, trained: Any, meta: TrainMeta,
                         plan: RoundPlan) -> None:
         bank = self.registry.params
         agg_idx, keep, w = self._shard_agg_plan(
             self._rows(plan.agg_models), meta, plan.scores)
-        bank.swap(self._apply(bank.tree, trained, w, agg_idx, keep))
+        with spans.span("fedcd.launch.dispatch"):
+            bank.swap(self._apply(bank.tree, trained, w, agg_idx, keep))
 
     def _finish_round(self, trained: Any, meta: TrainMeta,
-                      plan: RoundPlan) -> Dict[str, Callable]:
+                      plan: RoundPlan) -> Dict[str, _Pending]:
         bank = self.registry.params
         agg_idx, keep, w = self._shard_agg_plan(
             self._rows(plan.agg_models), meta, plan.scores)
@@ -1006,11 +1068,12 @@ class ShardedExecutor(FusedExecutor):
             self._rows(plan.val_stale or plan.live[:1]))
         tidx, tpos = self._shard_row_slots(
             self._rows(plan.test_stale or plan.live[:1]))
-        new_stacked, val_mat, test_mat = self._finish(
-            bank.tree, trained, w, agg_idx, keep, vidx, tidx,
-            *self._dev["val"], *self._dev["test"])
+        with spans.span("fedcd.launch.dispatch"):
+            new_stacked, val_mat, test_mat = self._finish(
+                bank.tree, trained, w, agg_idx, keep, vidx, tidx,
+                *self._dev["val"], *self._dev["test"])
         bank.swap(new_stacked)
-        pend: Dict[str, Callable] = {}
+        pend: Dict[str, _Pending] = {}
         if plan.val_stale:
             pend["val"] = self._sharded_reader(val_mat, plan.val_stale,
                                                vpos)
@@ -1020,29 +1083,32 @@ class ShardedExecutor(FusedExecutor):
         return pend
 
     def _sharded_reader(self, fut: Any, models: List[int],
-                        pos: Dict[int, int]) -> Callable:
+                        pos: Dict[int, int]) -> _Pending:
         row_of = self.registry.params.row_of
 
         def read() -> Dict[int, np.ndarray]:
-            mat = np.asarray(fut)         # the eval all-gather boundary
+            mat = _to_host(fut)           # the eval all-gather boundary
             return {m: self._to_id_row(mat[pos[row_of[m]]])
                     for m in models}
-        return read
+        return _Pending(fut, read)
 
-    def _dispatch_dense(self, models: List[int], split: str) -> Callable:
+    def _dispatch_dense(self, models: List[int], split: str) -> _Pending:
         idx, pos = self._shard_row_slots(self._rows(models))
-        fut = self._eval(self.registry.params.tree, idx,
-                         *self._dev[split])
+        with spans.span("fedcd.launch.dispatch"):
+            fut = self._eval(self.registry.params.tree, idx,
+                             *self._dev[split])
         return self._sharded_reader(fut, models, pos)
 
-    def _dispatch_sparse_val(self, plan: RoundPlan) -> Callable:
-        m_idx, d_idx, groups, width = shard_eval_pairs(
-            self._rows(plan.val_pair_model),
-            self._drows(plan.val_pair_device),
-            self._rows_per_shard, self._n_shards,
-            minimum=max(8 // self._n_shards, 2))
-        fut = self._pair_eval(self.registry.params.tree, m_idx, d_idx,
-                              *self._dev["val"])
+    def _dispatch_sparse_val(self, plan: RoundPlan) -> _Pending:
+        with spans.span("fedcd.launch.args"):
+            m_idx, d_idx, groups, width = shard_eval_pairs(
+                self._rows(plan.val_pair_model),
+                self._drows(plan.val_pair_device),
+                self._rows_per_shard, self._n_shards,
+                minimum=max(8 // self._n_shards, 2))
+        with spans.span("fedcd.launch.dispatch"):
+            fut = self._pair_eval(self.registry.params.tree, m_idx, d_idx,
+                                  *self._dev["val"])
         positions = [0] * len(plan.val_pair_model)
         for s, g in enumerate(groups):
             for j, k in enumerate(g):
@@ -1061,12 +1127,13 @@ class ShardedExecutor(FusedExecutor):
                 self._rows(plan.val_stale or plan.live[:1]))
             tidx, tpos = self._shard_row_slots(
                 self._rows(plan.test_stale or plan.live[:1]))
-            new_stacked, val_mat, test_mat = self._round(
-                bank.tree, m_idx, d_idx, pperms, w, agg_idx, keep,
-                vidx, tidx, *self._dev["train"], *self._dev["val"],
-                *self._dev["test"])
+            with spans.span("fedcd.launch.dispatch"):
+                new_stacked, val_mat, test_mat = self._round(
+                    bank.tree, m_idx, d_idx, pperms, w, agg_idx, keep,
+                    vidx, tidx, *self._dev["train"], *self._dev["val"],
+                    *self._dev["test"])
             bank.swap(new_stacked)
-            pend: Dict[str, Callable] = {}
+            pend: Dict[str, _Pending] = {}
             if plan.val_stale:
                 pend["val"] = self._sharded_reader(val_mat,
                                                    plan.val_stale, vpos)
@@ -1088,12 +1155,14 @@ class ShardedExecutor(FusedExecutor):
         self._pending = (plan, pend)
 
     def eval_rows(self, rows: List[int], split: str) -> np.ndarray:
-        row_of = self.registry.params.row_of
-        idx, pos = self._shard_row_slots(self._rows(rows))
-        mat = np.asarray(self._eval(self.registry.params.tree, idx,
-                                    *self._dev[split]))
-        return np.stack([self._to_id_row(mat[pos[row_of[m]]])
-                         for m in rows])
+        with spans.span("fedcd.collect.eval"):
+            spans.count("eval_rows_extra", len(rows))
+            row_of = self.registry.params.row_of
+            idx, pos = self._shard_row_slots(self._rows(rows))
+            mat = np.asarray(self._eval(self.registry.params.tree, idx,
+                                        *self._dev[split]))
+            return np.stack([self._to_id_row(mat[pos[row_of[m]]])
+                             for m in rows])
 
 
 class Sharded2DExecutor(ShardedExecutor):
@@ -1156,15 +1225,17 @@ class Sharded2DExecutor(ShardedExecutor):
                                TrainMeta]:
         # the global work now splits Sm*Sd ways — scale the bucket
         # floor with the cell count, not the model-shard count
-        m_idx, d_idx, pperms, cell_groups, b_pad = shard_pairs_2d(
-            self._rows(pair_model), self._drows(pair_device),
-            [perms[d] for d in pair_device], self._rows_per_shard,
-            self._n_shards, self.databank.rows_per_shard,
-            self._n_dshards, minimum=max(8 // self._n_cells, 2))
-        meta = TrainMeta(list(pair_model), list(pair_device), b_pad,
-                         cell_groups,
-                         positions=_group_positions(cell_groups, b_pad,
-                                                    len(pair_model)))
+        with spans.span("fedcd.launch.args"):
+            m_idx, d_idx, pperms, cell_groups, b_pad = shard_pairs_2d(
+                self._rows(pair_model), self._drows(pair_device),
+                [perms[d] for d in pair_device], self._rows_per_shard,
+                self._n_shards, self.databank.rows_per_shard,
+                self._n_dshards, minimum=max(8 // self._n_cells, 2))
+            meta = TrainMeta(list(pair_model), list(pair_device), b_pad,
+                             cell_groups,
+                             positions=_group_positions(cell_groups, b_pad,
+                                                        len(pair_model)))
+        _count_pairs(len(pair_model), len(m_idx))
         return m_idx, d_idx, pperms, meta
 
     def _shard_agg_plan(self, agg_rows: List[int], meta: TrainMeta,
@@ -1178,38 +1249,41 @@ class Sharded2DExecutor(ShardedExecutor):
         data-shard blocks), so duplicate scatter indices still carry
         identical post-psum values; empty model shards keep-mask their
         rows to existing values exactly as in the 1-D plan."""
-        S_m, S_d = self._n_shards, self._n_dshards
-        row_of = self.registry.params.row_of
-        agg_idx, agg_groups, a_pad = shard_rows(
-            agg_rows, self._rows_per_shard, S_m, minimum=self._row_floor)
-        keep = np.zeros(S_m * a_pad, bool)
-        w = np.zeros((S_m * a_pad, S_d * meta.b_pad), np.float32)
-        for sm, group in enumerate(agg_groups):
-            if not group:
-                continue
-            base = sm * a_pad
-            keep[base:base + a_pad] = True
-            slot = {r: j for j, r in enumerate(group)}
-            for sd in range(S_d):
-                cbase = sd * meta.b_pad
-                for col, k in enumerate(
-                        meta.pair_groups[sm * S_d + sd]):
-                    m, d = meta.pair_model[k], meta.pair_device[k]
-                    j = slot.get(row_of[m])
-                    if j is not None:
-                        w[base + j, cbase + col] = c[d, m]
-            w[base + len(group):base + a_pad] = w[base]
-        return agg_idx, keep, w
+        with spans.span("fedcd.launch.args"):
+            S_m, S_d = self._n_shards, self._n_dshards
+            row_of = self.registry.params.row_of
+            agg_idx, agg_groups, a_pad = shard_rows(
+                agg_rows, self._rows_per_shard, S_m, minimum=self._row_floor)
+            keep = np.zeros(S_m * a_pad, bool)
+            w = np.zeros((S_m * a_pad, S_d * meta.b_pad), np.float32)
+            for sm, group in enumerate(agg_groups):
+                if not group:
+                    continue
+                base = sm * a_pad
+                keep[base:base + a_pad] = True
+                slot = {r: j for j, r in enumerate(group)}
+                for sd in range(S_d):
+                    cbase = sd * meta.b_pad
+                    for col, k in enumerate(
+                            meta.pair_groups[sm * S_d + sd]):
+                        m, d = meta.pair_model[k], meta.pair_device[k]
+                        j = slot.get(row_of[m])
+                        if j is not None:
+                            w[base + j, cbase + col] = c[d, m]
+                w[base + len(group):base + a_pad] = w[base]
+            return agg_idx, keep, w
 
-    def _dispatch_sparse_val(self, plan: RoundPlan) -> Callable:
-        m_idx, d_idx, groups, width = shard_eval_pairs_2d(
-            self._rows(plan.val_pair_model),
-            self._drows(plan.val_pair_device),
-            self._rows_per_shard, self._n_shards,
-            self.databank.rows_per_shard, self._n_dshards,
-            minimum=max(8 // self._n_cells, 2))
-        fut = self._pair_eval(self.registry.params.tree, m_idx, d_idx,
-                              *self._dev["val"])
+    def _dispatch_sparse_val(self, plan: RoundPlan) -> _Pending:
+        with spans.span("fedcd.launch.args"):
+            m_idx, d_idx, groups, width = shard_eval_pairs_2d(
+                self._rows(plan.val_pair_model),
+                self._drows(plan.val_pair_device),
+                self._rows_per_shard, self._n_shards,
+                self.databank.rows_per_shard, self._n_dshards,
+                minimum=max(8 // self._n_cells, 2))
+        with spans.span("fedcd.launch.dispatch"):
+            fut = self._pair_eval(self.registry.params.tree, m_idx, d_idx,
+                                  *self._dev["val"])
         positions = [0] * len(plan.val_pair_model)
         for cell, g in enumerate(groups):
             for j, k in enumerate(g):

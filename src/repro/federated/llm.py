@@ -207,7 +207,7 @@ class FedLLMTrainer:
             self._faults.check(t, phase)
 
     def run_round(self, t: int) -> LLMRoundMetrics:
-        t0 = time.time()
+        t0 = time.perf_counter_ns()
         fed = self.fed
         participating, tokens, labels, vt, vl = self._round_inputs(t)
         c = normalized_scores(self.state)
@@ -257,7 +257,8 @@ class FedLLMTrainer:
             mean_loss=float(np.mean(losses)) if losses else float("nan"),
             client_acc=best, live_models=len(self.registry.live_ids()),
             active_models=int(self.state.active.sum()),
-            score_std=float(stds.mean()), wall_s=time.time() - t0,
+            score_std=float(stds.mean()),
+            wall_s=(time.perf_counter_ns() - t0) * 1e-9,
             trained_models=len(losses))
         self.metrics.append(m)
         self._fault(t, "post-readback")

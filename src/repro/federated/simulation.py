@@ -143,9 +143,10 @@ def _train_pairs(one_pair: Callable, stacked, m_idx, xs, ys, d_idx, perms):
     pairs vmapped at a time, the groups in a sequential ``lax.map``.
     Each pair's computation is ``one_pair`` exactly as under a full
     ``vmap``; only how many run at once is bounded."""
-    return jax.lax.map(
-        lambda a: one_pair(stacked, a[0], xs, ys, a[1], a[2]),
-        (m_idx, d_idx, perms), batch_size=PAIR_GROUP)
+    with jax.named_scope("fedcd.train"):
+        return jax.lax.map(
+            lambda a: one_pair(stacked, a[0], xs, ys, a[1], a[2]),
+            (m_idx, d_idx, perms), batch_size=PAIR_GROUP)
 
 
 def make_pair_train(loss_fn: Callable, lr: float) -> Callable:
@@ -194,40 +195,43 @@ def _aggregate_rows(trained, w, quantize_bits: int,
     layouts), so the aggregation/transport semantics the equivalence
     tiers pin can never diverge between the sync and pipelined
     programs."""
-    agg = multi_weighted_average(trained, w, use_kernel=use_agg_kernel)
-    if quantize_bits:
-        from repro.core import quantize as qz
-        agg = jax.vmap(lambda t: qz.roundtrip(t, quantize_bits))(agg)
-    return agg
+    with jax.named_scope("fedcd.aggregate"):
+        agg = multi_weighted_average(trained, w, use_kernel=use_agg_kernel)
+        if quantize_bits:
+            from repro.core import quantize as qz
+            agg = jax.vmap(lambda t: qz.roundtrip(t, quantize_bits))(agg)
+        return agg
 
 
 def _scatter_rows(stacked, agg, agg_rows, keep=None):
     """Step 4: the idempotent-padding scatter writeback; with ``keep``
     the keep-masked sharded variant (empty shards rewrite their rows'
     existing values, so padding can never zero a live row)."""
-    if keep is None:
-        return jax.tree.map(
-            lambda old, new: old.at[agg_rows].set(new.astype(old.dtype)),
-            stacked, agg)
-
     def write(old, new):
         cur = old[agg_rows]
         k = keep.reshape((-1,) + (1,) * (cur.ndim - 1))
         return old.at[agg_rows].set(jnp.where(k, new.astype(old.dtype),
                                               cur))
 
-    return jax.tree.map(write, stacked, agg)
+    with jax.named_scope("fedcd.scatter"):
+        if keep is None:
+            return jax.tree.map(
+                lambda old, new: old.at[agg_rows].set(new.astype(old.dtype)),
+                stacked, agg)
+        return jax.tree.map(write, stacked, agg)
 
 
-def _eval_gathered(eval_model, stacked, idx, xs, ys):
+def _eval_gathered(eval_model, stacked, idx, xs, ys, split=None):
     """Step 5: score each scheduled bank row on every device's split —
     the (rows, N) accuracy matrix. Rows run one after another in a
     ``lax.map``, so peak memory is one row's eval over all N splits
-    whatever the number of rows."""
+    whatever the number of rows. Named ``fedcd.eval_<split>`` in the
+    program where the caller knows the split, else ``fedcd.eval``."""
     def one_row(i):
         return eval_model(jax.tree.map(lambda a: a[i], stacked), xs, ys)
 
-    return jax.lax.map(one_row, idx)
+    with jax.named_scope(f"fedcd.eval_{split}" if split else "fedcd.eval"):
+        return jax.lax.map(one_row, idx)
 
 
 def make_fused_round(loss_fn: Callable, acc_fn: Callable, lr: float,
@@ -280,8 +284,10 @@ def make_fused_round(loss_fn: Callable, acc_fn: Callable, lr: float,
                                d_idx, perms)
         agg = _aggregate_rows(trained, w, quantize_bits, use_agg_kernel)
         new_stacked = _scatter_rows(stacked, agg, agg_rows)
-        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
-        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty)
+        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy,
+                             "val")
+        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty,
+                              "test")
         return new_stacked, val, test
 
     return jax.jit(round_step, donate_argnums=(0,))
@@ -319,8 +325,10 @@ def make_fused_finish(acc_fn: Callable, quantize_bits: int = 0,
                     vx, vy, tx, ty):
         agg = _aggregate_rows(trained, w, quantize_bits, use_agg_kernel)
         new_stacked = _scatter_rows(stacked, agg, agg_rows)
-        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
-        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty)
+        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy,
+                             "val")
+        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty,
+                              "test")
         return new_stacked, val, test
 
     return jax.jit(finish_step, donate_argnums=(0,))
@@ -454,8 +462,10 @@ def make_sharded_round(loss_fn: Callable, acc_fn: Callable, lr: float,
                                d_idx, perms)
         agg = _aggregate_rows(trained, w, quantize_bits, use_agg_kernel)
         new_stacked = _scatter_rows(stacked, agg, agg_rows, keep=agg_keep)
-        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
-        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty)
+        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy,
+                             "val")
+        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty,
+                              "test")
         return new_stacked, val, test
 
     step = shard_map(
@@ -558,8 +568,10 @@ def make_sharded_finish(acc_fn: Callable, mesh: jax.sharding.Mesh,
              vx, vy, tx, ty):
         agg = _aggregate_rows(trained, w, quantize_bits, use_agg_kernel)
         new_stacked = _scatter_rows(stacked, agg, agg_rows, keep=agg_keep)
-        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
-        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty)
+        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy,
+                             "val")
+        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty,
+                              "test")
         return new_stacked, val, test
 
     step = shard_map(body, mesh=mesh,
@@ -698,19 +710,20 @@ def _aggregate_rows_psum(trained, w, quantize_bits: int, axis: str):
     columns split across the data shards — identical at one data shard,
     reduction-order float drift otherwise (the 2-D equivalence tier
     pins discrete state exactly and params to reduction order)."""
-    num = jax.tree.map(
-        lambda t: jnp.einsum("b...,ab->a...", t.astype(jnp.float32), w,
-                             precision=EQ1_PRECISION),
-        trained)
-    num = jax.lax.psum(num, axis)
-    den = jnp.maximum(jax.lax.psum(jnp.sum(w, axis=1), axis), 1e-12)
-    agg = jax.tree.map(
-        lambda n, t: (n / den.reshape((-1,) + (1,) * (n.ndim - 1))
-                      ).astype(t.dtype), num, trained)
-    if quantize_bits:
-        from repro.core import quantize as qz
-        agg = jax.vmap(lambda t: qz.roundtrip(t, quantize_bits))(agg)
-    return agg
+    with jax.named_scope("fedcd.aggregate"):
+        num = jax.tree.map(
+            lambda t: jnp.einsum("b...,ab->a...", t.astype(jnp.float32), w,
+                                 precision=EQ1_PRECISION),
+            trained)
+        num = jax.lax.psum(num, axis)
+        den = jnp.maximum(jax.lax.psum(jnp.sum(w, axis=1), axis), 1e-12)
+        agg = jax.tree.map(
+            lambda n, t: (n / den.reshape((-1,) + (1,) * (n.ndim - 1))
+                          ).astype(t.dtype), num, trained)
+        if quantize_bits:
+            from repro.core import quantize as qz
+            agg = jax.vmap(lambda t: qz.roundtrip(t, quantize_bits))(agg)
+        return agg
 
 
 def make_sharded2d_round(loss_fn: Callable, acc_fn: Callable, lr: float,
@@ -752,8 +765,10 @@ def make_sharded2d_round(loss_fn: Callable, acc_fn: Callable, lr: float,
                                d_idx, perms)
         agg = _aggregate_rows_psum(trained, w, quantize_bits, "data")
         new_stacked = _scatter_rows(stacked, agg, agg_rows, keep=agg_keep)
-        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
-        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty)
+        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy,
+                             "val")
+        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty,
+                              "test")
         return new_stacked, val, test
 
     step = shard_map(
@@ -821,8 +836,10 @@ def make_sharded2d_finish(acc_fn: Callable, mesh: jax.sharding.Mesh,
              vx, vy, tx, ty):
         agg = _aggregate_rows_psum(trained, w, quantize_bits, "data")
         new_stacked = _scatter_rows(stacked, agg, agg_rows, keep=agg_keep)
-        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy)
-        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty)
+        val = _eval_gathered(eval_model, new_stacked, live_idx, vx, vy,
+                             "val")
+        test = _eval_gathered(eval_model, new_stacked, test_idx, tx, ty,
+                              "test")
         return new_stacked, val, test
 
     step = shard_map(
