@@ -58,8 +58,7 @@ from repro.core.aggregate import multi_weighted_average  # noqa: E402
 from repro.core.fedcd import FedCDServer  # noqa: E402
 from repro.data.partition import (hierarchical_devices,  # noqa: E402
                                   stack_devices)
-from repro.federated.simulation import (bucket_size,  # noqa: E402
-                                        pad_live_rows)
+from repro.federated.simulation import pad_live_rows  # noqa: E402
 from repro.kernels.quantize import ops as qops  # noqa: E402
 from repro.kernels.quantize import ref as qref  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
@@ -112,8 +111,9 @@ def make_problem(seed: int, split=SPLIT):
 
 class Run:
     """One ``FedCDServer`` driven round by round, recording each
-    round's metrics, smoke wall time and the (B, L) buckets the
-    planner produced."""
+    round's metrics, smoke wall time and its (B, L) buckets: B the
+    train slots the executor dispatched (0 where it batches no pairs),
+    L the eval rows."""
 
     def __init__(self, label: str, cfg, spec: str, data, params):
         self.label = label
@@ -121,16 +121,26 @@ class Run:
                                batch_size=BATCH, spec=spec)
         self.metrics = []
         self.buckets = []
-        launch = self.srv.executor.launch
+        executor = self.srv.executor
+        launch = executor.launch
+        batch_args = getattr(executor, "_batch_args", None)
+        slots = [0]
+
+        def count_slots(*args):
+            out = batch_args(*args)
+            slots[0] = len(out[0])          # the train batch's m_idx
+            return out
 
         def observe(plan):
-            self.buckets.append(
-                (bucket_size(len(plan.pair_model)) if plan.pair_model
-                 else 0,
-                 len(pad_live_rows(plan.val_stale or plan.live[:1]))))
+            slots[0] = 0
             launch(plan)
+            self.buckets.append(
+                (slots[0],
+                 len(pad_live_rows(plan.val_stale or plan.live[:1]))))
 
-        self.srv.executor.launch = observe
+        if batch_args is not None:
+            executor._batch_args = count_slots
+        executor.launch = observe
 
     def bank(self):
         reg = self.srv.registry

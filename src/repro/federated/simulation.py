@@ -71,8 +71,9 @@ def bucket_size(n: int, minimum: int = 8) -> int:
     shapes per octave instead of retracing every round; padding waste
     ``(bucket - n) / bucket`` stays < 20% once ``n > 8 * minimum``
     (worst case just past a power of two, e.g. n=65 -> 80; smaller
-    octaves clamp the step to ``minimum``, so e.g. n=9 pads to 16).
-    Property-tested in tests/test_property.py."""
+    octaves clamp the step to ``minimum``, so n=9 pads to 16 at the
+    default floor of 8 and to 10 at the train batch's floor of 2,
+    ``train_bucket``). Property-tested in tests/test_property.py."""
     if n <= minimum:
         return minimum
     octave = 1 << (n - 1).bit_length()          # next power of two >= n
@@ -80,15 +81,24 @@ def bucket_size(n: int, minimum: int = 8) -> int:
     return -(-n // step) * step
 
 
+def train_bucket(n: int) -> int:
+    """Slots of the one-chip train work batch for ``n`` pairs. A padding
+    pair costs a full forward and backward pass, so the floor is 2 (the
+    sharded engines' per-shard floor), not 8: 10 pairs train as 10
+    slots. Above 64 pairs the eighth-octave step is >= 8 and the buckets
+    are the default floor's."""
+    return bucket_size(n, minimum=2)
+
+
 def pad_work_batch(model_idx: "list[int]", device_idx: "list[int]",
-                   perm_rows: "list[np.ndarray]", minimum: int = 8
+                   perm_rows: "list[np.ndarray]"
                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Pad gathered (model, device, perm) pair lists to one static
-    bucket for the group train step. Padding pairs point at model 0 /
-    device 0 with all-zero perms; callers mask them out of aggregation
-    with zero weight columns."""
+    bucket (``train_bucket``) for the group train step. Padding pairs
+    point at model 0 / device 0 with all-zero perms; callers mask them
+    out of aggregation with zero weight columns."""
     b = len(model_idx)
-    b_pad = bucket_size(b, minimum)
+    b_pad = train_bucket(b)
     m_idx = np.zeros(b_pad, np.int32)
     m_idx[:b] = model_idx
     d_idx = np.zeros(b_pad, np.int32)
@@ -132,21 +142,35 @@ def _pair_train(loss_fn: Callable, lr: float) -> Callable:
     return one_pair
 
 
-# Work pairs trained side by side in one step of the sequential map over
-# a work batch. Peak round memory then follows this constant and the
+# Most work pairs trained side by side in one step of the sequential map
+# over a work batch. Peak round memory then follows this constant and the
 # model, not the bucket B (which reaches 240 at the paper's regime).
 PAIR_GROUP = 8
 
 
+def pair_group_width(b: int) -> int:
+    """Pairs per step of the map over a ``b``-slot work batch: the
+    largest divisor of ``b`` not above ``PAIR_GROUP``, so the groups are
+    equal and the program holds one train body (10 slots train as 2
+    groups of 5, where a width of 8 would add a vmapped remainder of 2).
+    Every multiple of 8 keeps 8; up to 8 slots train as one group."""
+    for w in range(min(b, PAIR_GROUP), 1, -1):
+        if b % w == 0:
+            return w
+    return 1
+
+
 def _train_pairs(one_pair: Callable, stacked, m_idx, xs, ys, d_idx, perms):
-    """Train every (model, device) pair of a work batch: ``PAIR_GROUP``
-    pairs vmapped at a time, the groups in a sequential ``lax.map``.
-    Each pair's computation is ``one_pair`` exactly as under a full
-    ``vmap``; only how many run at once is bounded."""
+    """Train every (model, device) pair of a work batch:
+    ``pair_group_width`` pairs vmapped at a time, read from the batch's
+    static shape, the groups in a sequential ``lax.map``. Each pair's
+    computation is ``one_pair`` exactly as under a full ``vmap``; only
+    how many run at once is bounded."""
     with jax.named_scope("fedcd.train"):
         return jax.lax.map(
             lambda a: one_pair(stacked, a[0], xs, ys, a[1], a[2]),
-            (m_idx, d_idx, perms), batch_size=PAIR_GROUP)
+            (m_idx, d_idx, perms),
+            batch_size=pair_group_width(m_idx.shape[0]))
 
 
 def make_pair_train(loss_fn: Callable, lr: float) -> Callable:

@@ -130,6 +130,108 @@ def test_pad_work_batch_padding_is_masked(n_pairs, models, dim):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+def test_train_bucket_wastes_little_and_groups_evenly():
+    """The one-chip train work batch for every pair count 1..256 wastes
+    under 2 slots or under 20% of the bucket, and trains as equal groups
+    whose width divides the bucket and is at most ``PAIR_GROUP`` (8 for
+    every multiple of 8, the whole bucket up to 8 slots). Above 64 pairs
+    the buckets are the floor of 8's, and over 1..256 at most 8 shapes
+    are new."""
+    from repro.federated.simulation import (PAIR_GROUP, bucket_size,
+                                            pair_group_width, train_bucket)
+    for n in range(1, 257):
+        b = train_bucket(n)
+        assert b >= n and (b - n < 2 or (b - n) / b < 0.2), (n, b)
+        w = pair_group_width(b)
+        assert b % w == 0 and w <= PAIR_GROUP, (n, b, w)
+        if b % 8 == 0:
+            assert w == 8, (n, b, w)
+        if b <= PAIR_GROUP:
+            assert w == b, (n, b, w)
+        if n > 64:
+            assert b == bucket_size(n), n
+    new = ({train_bucket(n) for n in range(1, 257)}
+           - {bucket_size(n) for n in range(1, 257)})
+    assert len(new) <= 8, sorted(new)
+
+
+def _pair_problem(n_pairs, models=3, n_dev=6, n_ex=16, dim=12):
+    """A stacked bank of small MLPs, data, and ``n_pairs`` (model,
+    device, perm) pairs that give every model at least one pair."""
+    import jax
+    from repro.models.mlp import init_mlp_classifier
+    rng = np.random.default_rng(n_pairs)
+    keys = jax.random.split(jax.random.PRNGKey(n_pairs), models)
+    stacked = jax.tree.map(
+        lambda *a: jnp.stack(a),
+        *[init_mlp_classifier(k, in_dim=dim, hidden=16) for k in keys])
+    xs = jnp.asarray(rng.normal(size=(n_dev, n_ex, dim)), jnp.float32)
+    ys = jnp.asarray(rng.integers(0, 10, (n_dev, n_ex)), jnp.int32)
+    model_idx = rng.permutation([k % models for k in range(n_pairs)])
+    device_idx = rng.integers(0, n_dev, n_pairs)
+    perm_rows = [rng.permutation(n_ex).reshape(2, n_ex // 2)
+                 .astype(np.int32) for _ in range(n_pairs)]
+    return (stacked, xs, ys, model_idx.tolist(), device_idx.tolist(),
+            perm_rows)
+
+
+@pytest.mark.parametrize("n_pairs", [10, 13])
+def test_train_bucket_trains_like_the_floor_of_8(n_pairs):
+    """Training ``n_pairs`` pairs padded to the floor of 8 (16 slots,
+    two groups of 8) and to ``train_bucket`` (10 slots as two groups of
+    5; 14 as two of 7) gives the same real trained rows and the same
+    eq-1 aggregate."""
+    import jax
+    from repro.core.aggregate import multi_weighted_average
+    from repro.federated.simulation import (make_pair_train,
+                                            pad_work_batch, train_bucket)
+    from repro.models.mlp import mlp_loss
+    stacked, xs, ys, model_idx, device_idx, perm_rows = _pair_problem(
+        n_pairs)
+    models = len(jax.tree.leaves(stacked)[0])
+    scores = np.random.default_rng(1).uniform(0.1, 1.0, n_pairs)
+    train = make_pair_train(mlp_loss, 0.1)
+    now = pad_work_batch(model_idx, device_idx, perm_rows)
+    assert len(now[0]) == train_bucket(n_pairs) < 16
+    # the floor of 8's batch: the same pairs, zero padding out to 16
+    floor8 = [np.concatenate([a, np.zeros((16 - len(a),) + a.shape[1:],
+                                          a.dtype)]) for a in now]
+    out = []
+    for m_idx, d_idx, perms in (floor8, now):
+        trained = train(stacked, m_idx, xs, ys, d_idx, perms)
+        w = np.zeros((models, len(m_idx)), np.float32)
+        w[model_idx, np.arange(n_pairs)] = scores
+        out.append((jax.tree.map(lambda a: a[:n_pairs], trained),
+                     multi_weighted_average(trained, w)))
+    for a, b in zip(jax.tree.leaves(out[0]), jax.tree.leaves(out[1])):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("b", [8, 16, 64])
+def test_train_program_of_multiples_of_8_is_unchanged(b):
+    """A work batch whose slots are a multiple of 8 traces the same
+    train program as the fixed groups of 8 did."""
+    import jax
+    from repro.federated.simulation import _pair_train, _train_pairs
+    from repro.models.mlp import mlp_loss
+    stacked, xs, ys, _, _, _ = _pair_problem(1)
+    one_pair = _pair_train(mlp_loss, 0.1)
+
+    def groups_of_8(stacked, m_idx, xs, ys, d_idx, perms):
+        with jax.named_scope("fedcd.train"):
+            return jax.lax.map(
+                lambda a: one_pair(stacked, a[0], xs, ys, a[1], a[2]),
+                (m_idx, d_idx, perms), batch_size=8)
+
+    i32 = np.int32
+    args = (stacked, np.zeros(b, i32), xs, ys, np.zeros(b, i32),
+            np.zeros((b, 2, 8), i32))
+    now = jax.make_jaxpr(
+        lambda *a: _train_pairs(one_pair, *a))(*args)
+    assert str(now) == str(jax.make_jaxpr(groups_of_8)(*args))
+
+
 @given(st.lists(st.integers(0, 15), min_size=1, max_size=16, unique=True))
 @settings(max_examples=30, deadline=None)
 def test_pad_live_rows_prefix_preserved(live):

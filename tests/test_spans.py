@@ -20,7 +20,7 @@ from repro import spans
 from repro.configs.fedcd_cifar import HIERARCHICAL
 from repro.core.fedcd import FedCDServer
 from repro.data.partition import hierarchical_devices, stack_devices
-from repro.federated.simulation import bucket_size, make_fused_round
+from repro.federated.simulation import make_fused_round, train_bucket
 from repro.models.mlp import init_mlp_classifier, mlp_accuracy, mlp_loss
 
 # spans below the root, each with the parent it must have in a plain
@@ -129,7 +129,7 @@ def test_on_records_the_round_spans_counters_and_wall():
     plan = plans[0]
     counts = rec.counts[2]
     assert counts["pairs"] == len(plan.pair_model) > 0
-    assert counts["pairs"] + counts["pairs_padded"] == bucket_size(
+    assert counts["pairs"] + counts["pairs_padded"] == train_bucket(
         len(plan.pair_model))
     assert counts["val_rows"] == len(plan.val_stale) > 0
     assert counts["test_rows"] == len(plan.test_stale)
@@ -198,7 +198,7 @@ def test_fused_round_program_carries_the_phase_scopes():
     xs, ys = srv.databank.splits["train"]
     vx, vy = srv.databank.splits["val"]
     t = 4
-    b = bucket_size(4)
+    b = train_bucket(4)
     prog = make_fused_round(mlp_loss, mlp_accuracy, 0.1)
     i32 = np.int32
     hlo = prog.lower(

@@ -4,9 +4,10 @@ Interpret mode (tests/test_kernels.py) cannot show what the TPU compiler
 refuses: block shapes off the (8, 128) tiling, loads from the wrong
 memory space, or a round program that outgrows the chip's HBM. These
 tests compile each Pallas kernel with ``interpret=False`` at the paper
-CNN's real leaf widths, and the fused round at the paper's regime
-(16 eval rows, a 240-pair bucket, 333 val/test examples per device),
-for one chip of a described ``v5e:2x2`` topology.
+CNN's real leaf widths, the fused round at the paper's regime (16 eval
+rows, a 240-pair bucket, 333 val/test examples per device) and at
+McMahan et al.'s FedSGD round (a 10-pair bucket), for one chip of a
+described ``v5e:2x2`` topology.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and each test worker imports every
@@ -19,7 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.federated.simulation import make_fused_round
+from repro.federated.simulation import (make_fused_round, pair_group_width,
+                                        train_bucket)
 from repro.kernels.quantize import kernel as QK
 from repro.kernels.weighted_agg import kernel as WK
 from repro.models.cnn import cnn_accuracy, cnn_loss, init_cnn
@@ -99,26 +101,30 @@ def test_kernel_compiles_for_v5e(name, one_chip, cnn_shapes):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _round_bytes(one_chip, cnn_shapes, rows: int) -> int:
+def _round_bytes(one_chip, cnn_shapes, rows: int, pairs: int = B_PAIRS,
+                 agg_rows: int = M_CAP, devices: int = N_DEVICES,
+                 n_train: int = N_TRAIN, n_eval: int = N_EVAL,
+                 batch: int = BATCH, epochs: int = EPOCHS) -> int:
     """temp + argument bytes of the fused round compiled for one v5e
-    with ``rows`` eval rows (val and test) and a ``B_PAIRS`` bucket."""
+    with ``rows`` eval rows (val and test) and a ``pairs``-slot train
+    bucket; the paper's regime unless told otherwise."""
     f32, i32 = jnp.float32, jnp.int32
-    steps = EPOCHS * (N_TRAIN // BATCH)
+    steps = epochs * (n_train // batch)
     bank = jax.tree.map(lambda a: _sds(one_chip, (M_CAP,) + a.shape,
                                        a.dtype), cnn_shapes)
     image = (32, 32, 3)
-    args = (bank, _sds(one_chip, (B_PAIRS,), i32),
-            _sds(one_chip, (B_PAIRS,), i32),
-            _sds(one_chip, (B_PAIRS, steps, BATCH), i32),
-            _sds(one_chip, (M_CAP, B_PAIRS), f32),
-            _sds(one_chip, (M_CAP,), i32),
+    args = (bank, _sds(one_chip, (pairs,), i32),
+            _sds(one_chip, (pairs,), i32),
+            _sds(one_chip, (pairs, steps, batch), i32),
+            _sds(one_chip, (agg_rows, pairs), f32),
+            _sds(one_chip, (agg_rows,), i32),
             _sds(one_chip, (rows,), i32), _sds(one_chip, (rows,), i32),
-            _sds(one_chip, (N_DEVICES, N_TRAIN) + image, f32),
-            _sds(one_chip, (N_DEVICES, N_TRAIN), i32),
-            _sds(one_chip, (N_DEVICES, N_EVAL) + image, f32),
-            _sds(one_chip, (N_DEVICES, N_EVAL), i32),
-            _sds(one_chip, (N_DEVICES, N_EVAL) + image, f32),
-            _sds(one_chip, (N_DEVICES, N_EVAL), i32))
+            _sds(one_chip, (devices, n_train) + image, f32),
+            _sds(one_chip, (devices, n_train), i32),
+            _sds(one_chip, (devices, n_eval) + image, f32),
+            _sds(one_chip, (devices, n_eval), i32),
+            _sds(one_chip, (devices, n_eval) + image, f32),
+            _sds(one_chip, (devices, n_eval), i32))
     step = make_fused_round(cnn_loss, cnn_accuracy, 0.08)
     mem = step.lower(*args).compile().memory_analysis()
     return mem.temp_size_in_bytes + mem.argument_size_in_bytes
@@ -131,3 +137,18 @@ def test_fused_round_fits_one_v5e_bounded_by_shape(one_chip, cnn_shapes):
     one = _round_bytes(one_chip, cnn_shapes, rows=1)
     assert full < 0.75 * HBM_BYTES, full
     assert abs(full - one) <= 0.10 * one, (full, one)
+
+
+def test_fedsgd_round_at_its_ten_slot_bucket_fits_the_sixteen_slot_peak(
+        one_chip, cnn_shapes):
+    """McMahan et al.'s CIFAR-10 FedSGD round (100 devices of 500
+    examples, 10 pairs of one 500-example step, one model, one val and
+    one test row over 100 examples a device) compiles at its train
+    bucket of 10 slots, two groups of 5, and needs no more memory than
+    at the 16 slots the floor of 8 gave it."""
+    cell = dict(rows=1, agg_rows=1, devices=100, n_train=500, n_eval=100,
+                batch=500, epochs=1)
+    assert train_bucket(10) == 10 and pair_group_width(10) == 5
+    ten = _round_bytes(one_chip, cnn_shapes, pairs=10, **cell)
+    sixteen = _round_bytes(one_chip, cnn_shapes, pairs=16, **cell)
+    assert ten <= sixteen, (ten, sixteen)
