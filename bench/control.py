@@ -12,7 +12,8 @@ a benchmark run's set-up (``run.begin``), against the plain reference
 reference put in the program's place: the control (``control_fp8``, its
 convolutions' and dense layers' operands in float8) and each planted
 fault of ``reference.run_reference`` (``half_batch``,
-``altered_answer``); ``unchanged`` reads 1 on the update gaps by
+``altered_answer``, and from round 2 on ``unchanged_from_r2``,
+``uniform_from_r2``); ``unchanged`` reads 1 on the update gaps by
 construction and is not run. Prints one JSON line per seed and reading:
 the numbers ``correct.compare`` gives, and the per-leaf gaps of model 0
 after the first and the last compared round. The benchmark's own runs
@@ -36,20 +37,22 @@ import numpy as np  # noqa: E402
 
 IN_PLACE = {"control_fp8": dict(operands="fp8"),
             "half_batch": dict(fault="half_batch"),
-            "altered_answer": dict(fault="altered_answer")}
+            "altered_answer": dict(fault="altered_answer"),
+            "unchanged_from_r2": dict(fault="unchanged_from_r2"),
+            "uniform_from_r2": dict(fault="uniform_from_r2")}
 
 
 def readings(spec, seed: int, in_place=tuple(IN_PLACE)):
-    setup = {}
-    inputs, params0, fed, srv, probe = run.begin(spec, seed, setup)
-    probe.close()
-    captured = [probe.captured[r] for r in sorted(probe.captured)]
-    del srv, probe
+    setup = run.Setup()
+    session = run.begin(spec, seed, setup)
+    session.release()
     gc.collect()
     t0 = time.perf_counter()
-    numbers = run.judge(spec, seed, captured, inputs, params0, fed)
+    numbers = run.judge(spec, seed, session)
     yield "program", dict(numbers, seconds=time.perf_counter() - t0,
                           **setup)
+    inputs, params0, fed = session.inputs, session.params0, session.fed
+    captured = session.compared
     host0 = jax.tree.map(lambda a: np.asarray(a, np.float32), params0)
     config = spec["config"]
     ref = reference.run_reference(
@@ -60,7 +63,7 @@ def readings(spec, seed: int, in_place=tuple(IN_PLACE)):
         got = reference.run_reference(
             fed, config["batch_size"], inputs, params0, seed, len(captured),
             precision=config["operand_precision"], **IN_PLACE[name])
-        out = correct.compare(got, ref, host0)
+        out = correct.compare(got, ref, host0, window=fed["score_window"])
         out["seconds"] = time.perf_counter() - t0
         out["leaves_r1"] = correct.leaf_gaps(
             got[0].params[0], ref[0].params[0], host0)

@@ -299,6 +299,7 @@ def run_phases(spec: Dict, seed: int, seconds: float, keep: str = None,
     import jax
 
     from bench import data, run, trace
+    from bench.runners.fedcd_fused import bank_tree, server_config
     from repro import spans
     from repro.core.fedcd import FedCDServer
     from repro.launch.compile_cache import enable_compile_cache
@@ -311,7 +312,7 @@ def run_phases(spec: Dict, seed: int, seconds: float, keep: str = None,
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     run.check_chips(spec["cell"]["chips"])
     config, traffic = spec["config"], spec["traffic"]
-    cfg, _ = run.server_config(config, traffic, seed)
+    cfg, _ = server_config(config, traffic, seed)
     srv = FedCDServer(cfg, data.make_params(seed, config["model"]),
                       cnn_loss, cnn_accuracy,
                       data.make_data(seed, config["partition"]),
@@ -322,7 +323,7 @@ def run_phases(spec: Dict, seed: int, seconds: float, keep: str = None,
     for _ in range(traffic["compare_rounds"] + traffic["warm_rounds"]):
         t += 1
         srv.run_round(t)
-    jax.block_until_ready(run.bank_tree(srv))
+    jax.block_until_ready(bank_tree(srv))
     programs.clear()
 
     trace_dir = tempfile.mkdtemp(prefix="bench_phases_")
@@ -338,7 +339,7 @@ def run_phases(spec: Dict, seed: int, seconds: float, keep: str = None,
                 srv.run_round(t + n)
                 if time.perf_counter() - w0 >= seconds:
                     break
-            jax.block_until_ready(run.bank_tree(srv))
+            jax.block_until_ready(bank_tree(srv))
             w1 = time.perf_counter()
         jax.profiler.stop_trace()
         recorded = spans.drain()

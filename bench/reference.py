@@ -23,7 +23,9 @@ gradient passed straight through.
 ``unchanged`` (the round leaves the weights as they were),
 ``half_batch`` (every other pair of a model left out of eq 1, the mean
 taken over the rest), ``altered_answer`` (the accuracy matrix computed
-against the next label).
+against the next label); from round 2 on, after a sound round 1,
+``unchanged_from_r2`` (as ``unchanged``) and ``uniform_from_r2`` (eq 3
+ignored: every pair of a model weighted alike).
 """
 from __future__ import annotations
 
@@ -143,6 +145,7 @@ class RoundRecord:
     live: List[int]
     accs: np.ndarray                              # (N, len(live))
     params: Dict[int, dict] = field(default_factory=dict)   # after eq 1
+    cohort: Optional[np.ndarray] = None           # the round's devices
 
 
 def run_reference(fed: dict, batch: int, data: dict, params0: dict,
@@ -172,6 +175,9 @@ def run_reference(fed: dict, batch: int, data: dict, params0: dict,
     active[:, 0] = True
     out: List[RoundRecord] = []
     for t in range(1, rounds + 1):
+        now = fault
+        if fault is not None and fault.endswith("_from_r2"):
+            now = fault[:-len("_from_r2")] if t >= 2 else None
         part, perms = draw_sample(rng, n, fed["devices_per_round"],
                                   xs.shape[1], batch, fed["local_epochs"])
         c = normalized_scores(history, active)
@@ -181,17 +187,19 @@ def run_reference(fed: dict, batch: int, data: dict, params0: dict,
             if not len(devs):
                 continue
             w = c[devs, m].astype(np.float32)
-            if fault == "half_batch":
+            if now == "half_batch":
                 w[1::2] = 0.0
+            if now == "uniform":
+                w[:] = 1.0
             trained = [train_pair(bank[m], xs[d], ys[d], perms[d], lr,
                                   prec, operands) for d in devs]
             denom = np.float32(max(float(w.sum()), 1e-12))
-            if fault != "unchanged":
+            if now != "unchanged":
                 bank[m] = jax.tree.map(
                     lambda *leaves: sum(
                         np.float32(wk) * leaf for wk, leaf in zip(w, leaves)
                     ) / denom, *trained)
-        label_shift = 1 if fault == "altered_answer" else 0
+        label_shift = 1 if now == "altered_answer" else 0
         accs = np.zeros((n, m_cap))
         for m in live:
             accs[:, m] = np.asarray(accuracy_row(
@@ -199,7 +207,7 @@ def run_reference(fed: dict, batch: int, data: dict, params0: dict,
         out.append(RoundRecord(
             live, accs[:, live],
             {m: jax.tree.map(lambda a: np.asarray(a, np.float32), bank[m])
-             for m in live}))
+             for m in live}, np.flatnonzero(part)))
         rolled = np.roll(history, -1, axis=2)
         rolled[:, :, -1] = accs
         history = np.where(active[:, :, None], rolled, history)

@@ -1,29 +1,37 @@
 #!/usr/bin/env python3
-"""Run one benchmark cell of FedCD mode A once, on the chip.
+"""Run one benchmark cell once, on the chip.
 
     python3 bench/run.py --workload c10x100_fedsgd --seed 7 --seconds 10 --trace 0
 
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
-(``bench/configs/<config>.json``: the model, the FedCD server settings,
-the partition) and a traffic mix (``bench/traffic/<traffic>.json``: the
-milestone schedule, how many rounds are compared and warmed). The run:
+(``bench/configs/<config>.json``: the system's sizes and settings) and a
+traffic mix (``bench/traffic/<traffic>.json``: the schedule, how many
+rounds are compared and warmed). The configuration's ``"runner"`` key
+names the runner, ``bench/runners/<runner>.py``, that builds the system
+through its entry point (``fedcd_fused``, FedCD mode A, where the key is
+absent; the runner's contract is in ``runners/__init__.py``). The run:
 
-1. makes the data and the initial weights on the device from ``--seed``;
-2. builds ``FedCDServer`` with the default ``fused`` spec and runs its
-   first rounds, recording what the compared rounds produced and the
-   argument shapes of the round program each one ran;
-3. runs the traffic's warm-up rounds;
-4. runs rounds for ``--seconds`` (the measured window), counting
+1. the runner makes the data and the weights on the device from
+   ``--seed``, builds the system with ``Probe`` attached and runs its
+   first rounds, recording what the compared rounds produced;
+2. runs the traffic's warm-up rounds;
+3. runs rounds for ``--seconds`` (the measured window), counting
    compiles;
-5. frees the server and compares the recorded rounds with the plain
-   reference (``reference.py``, ``correct.py``); a compared round whose
-   round program the window did not run counts against ``correct``.
+4. has the runner free the system, and compares the recorded rounds
+   with the runner's plain reference (``judge``); a compared round
+   whose program (``session.programs``) the window did not run counts
+   against ``correct``.
 
-With ``--trace 1`` the window runs under the JAX profiler and the line
-carries the per-layer metrics; with ``--trace 0`` the end-to-end ones.
-Each metric is read by ``bench/metrics/<name>.py``. The last line of
-standard output is one JSON object; a run on a machine whose JAX finds
-no TPU, or fewer chips than the cell asks for, exits 1 and prints none.
+The set-up clock, the warm-up and the window, the compile count, the
+profiler trace and its reduction, the memory peak, the verdict against
+``bench/limits/<cell>.json``, the metrics and the result line are all
+here, the same for every runner. With ``--trace 1`` the window runs
+under the JAX profiler and the line carries the per-layer metrics; with
+``--trace 0`` the end-to-end ones. Each metric is read by
+``bench/metrics/<name>.py``. The last line of standard output is one
+JSON object; a run on a machine whose JAX finds no TPU, or fewer chips
+than the cell asks for, or whose configuration names no runner, exits 1
+and prints none.
 """
 from __future__ import annotations
 
@@ -50,15 +58,23 @@ for p in (str(ROOT / "src"), str(ROOT)):
         sys.path.insert(0, p)
 
 import jax  # noqa: E402
-import numpy as np  # noqa: E402
 
-from bench import correct, data, flops, reference, trace  # noqa: E402
+from bench import correct, trace  # noqa: E402
+# ``bench/tests/test_correct.py`` reads mode A's ``run.server_config``
+from bench.runners.fedcd_fused import server_config  # noqa: E402,F401
 
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_trace_duration")
 
 
+DEFAULT_RUNNER = "fedcd_fused"
+
+
 class NoChip(RuntimeError):
+    pass
+
+
+class BadCell(ValueError):
     pass
 
 
@@ -75,8 +91,9 @@ def load_cell(name: str) -> Dict:
     def applies(m):
         return name in m.get("workloads", [name])
 
-    return {
+    spec = {
         "cell": cell,
+        "config_file": cfg["file"],
         "config": json.loads((ROOT / cfg["file"]).read_text()),
         "traffic": json.loads(
             (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
@@ -85,87 +102,64 @@ def load_cell(name: str) -> Dict:
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
     }
+    runner_of(spec)
+    return spec
 
 
-# -- the benchmark's own spans around the server's calls ------------------
+# -- the runner, found by the configuration's "runner" key --------------
+
+def runner_of(spec: Dict):
+    """``bench.runners.<runner>`` of the cell's configuration."""
+    name = spec["config"].get("runner", DEFAULT_RUNNER)
+    module = f"bench.runners.{name}"
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise BadCell(
+            f'{spec.get("config_file", "the configuration")}: "runner": '
+            f"{name!r} names no module bench/runners/{name}.py") from None
+
+
+# -- the harness's own spans around the system's calls ---------------------
 
 class Probe:
-    """Wraps the server's planner, executor and lifecycle calls in named
-    host spans, records what each round planned and the argument shapes
-    of the round program it ran, counts compiles, and keeps the accuracy
-    matrix and the weights of the compared rounds."""
+    """Host spans named ``plan``, ``launch`` and ``readback`` around the
+    three calls that every FedCD entry point makes each round
+    (``planner.build``, ``executor.launch``, ``executor.readback``), a
+    record of each launched round (``{"round": plan.round,
+    **describe(plan)}``), and a count of compiles while ``counting``. A
+    runner attaches it to the system it builds and may span calls of its
+    own with ``wrap``."""
 
-    LIFECYCLE = ("push_accuracies", "apply_deletions", "clone_at_milestone")
-
-    def __init__(self, srv, capture_rounds, annotate: bool):
-        import repro.core.fedcd as fedcd_mod
-
-        self.srv = srv
+    def __init__(self, system, annotate: bool, describe):
+        self.system = system
         self.annotate = annotate
-        self.capture_rounds = set(capture_rounds)
-        self.captured: Dict[int, reference.RoundRecord] = {}
         self.spans: List[tuple] = []
         self.rounds: List[Dict] = []
-        self.programs: Dict[int, tuple] = {}
         self.compiles = 0
         self.compile_s = 0.0
         self.counting = False
+        self.plan = None
         self._round = 0
-        self._plan = None
-        ex = srv.executor
-        self._wrap(srv.planner, "build", "plan")
-        self._wrap(srv, "_draw_sample", "sample")
-        self._wrap(ex, "collect", "collect")
-        launch, readback, eval_rows = ex.launch, ex.readback, ex.eval_rows
-        round_program = ex._round
+        ex = system.executor
+        self.wrap(system.planner, "build", "plan")
+        launch = ex.launch
 
         def on_launch(plan):
-            self._plan = plan
-            self.rounds.append({
-                "round": plan.round, "pairs": len(plan.pair_model),
-                "live": len(plan.live), "val_rows": len(plan.val_stale),
-                "test_rows": len(plan.test_stale), "extra_test_rows": 0})
+            self.plan = plan
+            self.rounds.append({"round": plan.round, **describe(plan)})
             with self.span("launch"):
                 return launch(plan)
 
-        def on_readback():
-            with self.span("readback"):
-                result = readback()
-            t = self._plan.round
-            if t in self.capture_rounds:
-                live = list(self._plan.live)
-                reg = self.srv.registry
-                self.captured[t] = reference.RoundRecord(
-                    live, np.asarray(result.accs)[:, live].copy(),
-                    {m: jax.tree.map(lambda a: np.asarray(a, np.float32),
-                                     reg.params[m]) for m in live})
-            return result
-
-        def on_round_program(*args):
-            # (m_idx, d_idx, perms, w, agg_rows, live_idx, test_idx): the
-            # shapes that pick the compiled round program
-            self.programs[self._plan.round] = tuple(
-                np.shape(a) for a in args[1:8])
-            return round_program(*args)
-
-        def on_eval_rows(rows, split):
-            if split == "test":
-                self.rounds[-1]["extra_test_rows"] += len(rows)
-            return eval_rows(rows, split)
-
-        ex.launch, ex.readback, ex.eval_rows = (on_launch, on_readback,
-                                                on_eval_rows)
-        ex._round = on_round_program
-        self._fedcd = fedcd_mod
-        self._saved = {n: getattr(fedcd_mod, n) for n in self.LIFECYCLE}
-        for n, fn in self._saved.items():
-            setattr(fedcd_mod, n, self._spanned(fn, "lifecycle"))
+        ex.launch = on_launch
+        self.wrap(ex, "readback", "readback")
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
     def close(self) -> None:
-        for n, fn in self._saved.items():
-            setattr(self._fedcd, n, fn)
         jax.monitoring.unregister_event_duration_listener(self._on_event)
+        self.system = None
 
     def _on_event(self, event, duration, **_):
         if self.counting and event in COMPILE_EVENTS:
@@ -181,25 +175,31 @@ class Probe:
             yield
         self.spans.append((name, self._round, t0, time.perf_counter()))
 
-    def _spanned(self, fn, name):
+    def spanned(self, fn, name):
         def wrapped(*a, **k):
             with self.span(name):
                 return fn(*a, **k)
         return wrapped
 
-    def _wrap(self, obj, attr, name):
-        setattr(obj, attr, self._spanned(getattr(obj, attr), name))
+    def wrap(self, obj, attr, name):
+        setattr(obj, attr, self.spanned(getattr(obj, attr), name))
 
     def run_round(self, t: int):
         self._round = t
-        return self.srv.run_round(t)
+        return self.system.run_round(t)
 
     def reset(self) -> None:
         self.spans, self.rounds = [], []
 
 
-def bank_tree(srv):
-    return srv.registry.params.tree
+class Setup(dict):
+    """Seconds of each named part of set-up, for the log."""
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        t0 = time.perf_counter()
+        yield
+        self[name] = time.perf_counter() - t0
 
 
 # -- one run ---------------------------------------------------------------
@@ -213,63 +213,34 @@ def check_chips(n: int) -> None:
         raise NoChip(f"the cell needs {n} chips, JAX sees {len(devices)}")
 
 
-def server_config(config: Dict, traffic: Dict, seed: int):
-    from repro.config import FedCDConfig
-
-    fed = dict(config["fed"], milestones=tuple(traffic["milestones"]))
-    return FedCDConfig(seed=seed, **fed), fed
-
-
 def memory_peak() -> int:
     stats = [d.memory_stats() for d in jax.local_devices()]
     return max((s or {}).get("peak_bytes_in_use", 0) for s in stats)
 
 
-def begin(spec: Dict, seed: int, setup: Dict, fault=None,
+def begin(spec: Dict, seed: int, setup: Setup, fault=None,
           annotate: bool = False):
-    """Make the data and the weights from ``seed``, build the server
-    with the default ``fused`` spec and run the compared rounds through
-    it. Returns ``(inputs, params0, fed, srv, probe)``; ``setup`` gets
-    the times. ``fault`` (a callable given the server, which breaks its
-    timed path) exists for the tests."""
-    from repro.core.fedcd import FedCDServer
-    from repro.models.cnn import cnn_accuracy, cnn_loss
-
-    config, traffic = spec["config"], spec["traffic"]
-    t0 = time.perf_counter()
-    inputs = data.make_data(seed, config["partition"])
-    params0 = data.make_params(seed, config["model"])
-    jax.block_until_ready((inputs, params0))
-    setup["data_s"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    cfg, fed = server_config(config, traffic, seed)
-    srv = FedCDServer(cfg, params0, cnn_loss, cnn_accuracy, inputs,
-                      batch_size=config["batch_size"], spec="fused")
-    if fault is not None:
-        fault(srv)
-    n_compare = traffic["compare_rounds"]
-    probe = Probe(srv, range(1, n_compare + 1), annotate=annotate)
-    setup["server_s"] = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
-    for t in range(1, n_compare + 1):
-        probe.run_round(t)
-    jax.block_until_ready(bank_tree(srv))
-    setup["compared_s"] = time.perf_counter() - t0
-    return inputs, params0, fed, srv, probe
+    """The cell's runner's ``begin``, with ``Probe`` to attach: makes
+    the data and the weights from ``seed``, builds the system and runs
+    the compared rounds. Returns the runner's session; ``setup`` gets
+    the times of its parts."""
+    return runner_of(spec).begin(
+        spec, seed, setup,
+        lambda system, describe: Probe(system, annotate, describe), fault)
 
 
-def judge(spec: Dict, seed: int, captured: List, inputs: Dict,
-          params0: Dict, fed: Dict, **reference_kw) -> Dict[str, float]:
-    """The numbers of ``correct.compare``: the recorded rounds against
-    the plain reference run from the same seed."""
-    config = spec["config"]
-    ref = reference.run_reference(
-        fed, config["batch_size"], inputs, params0, seed, len(captured),
-        precision=config["operand_precision"], **reference_kw)
-    host0 = jax.tree.map(lambda a: np.asarray(a, np.float32), params0)
-    return correct.compare(captured, ref, host0)
+def judge(spec: Dict, seed: int, session) -> Dict[str, float]:
+    """The cell's runner's ``judge``: the numbers that ``limits`` names
+    but ``programs``, read after ``session.release()``."""
+    return runner_of(spec).judge(spec, seed, session)
+
+
+def stray_programs(programs: Dict[int, tuple], compared: List[int],
+                   window: List[int]) -> float:
+    """Compared rounds whose program (``programs``: round -> what picks
+    the compiled program) no window round ran."""
+    timed = {programs[t] for t in window}
+    return float(sum(programs[t] not in timed for t in compared))
 
 
 def run_cell(spec: Dict, seed: int, seconds: float, traced: bool,
@@ -282,26 +253,26 @@ def run_cell(spec: Dict, seed: int, seconds: float, traced: bool,
     ``tests/``."""
     from repro.launch.compile_cache import enable_compile_cache
 
-    config, traffic = spec["config"], spec["traffic"]
+    traffic = spec["traffic"]
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     if require_chip:
         check_chips(spec["cell"]["chips"])
     device = jax.devices()[0]
-    setup: Dict[str, float] = {}
-    inputs, params0, fed, srv, probe = begin(spec, seed, setup, fault,
-                                             annotate=traced)
+    setup = Setup()
+    session = begin(spec, seed, setup, fault, annotate=traced)
+    probe = session.probe
     n_compare = traffic["compare_rounds"]
 
     # warm-up: the traffic's rounds after the compared ones, so that the
     # window meets no program that a later round compiles first (the
     # traffic keeps the shapes fixed; the window counts compiles)
-    t0 = time.perf_counter()
     probe.counting = True
-    for t in range(n_compare + 1, n_compare + 1 + traffic["warm_rounds"]):
-        probe.run_round(t)
-    jax.block_until_ready(bank_tree(srv))
-    setup["warm_s"] = time.perf_counter() - t0
+    with setup.phase("warm_s"):
+        for t in range(n_compare + 1,
+                       n_compare + 1 + traffic["warm_rounds"]):
+            session.run_round(t)
+        jax.block_until_ready(session.state())
     setup["warm_compiles"], probe.compiles = probe.compiles, 0
     probe.reset()
     gc.collect()
@@ -317,24 +288,19 @@ def run_cell(spec: Dict, seed: int, seconds: float, traced: bool,
         w0 = time.perf_counter()
         n = 0
         while True:
-            probe.run_round(t + 1 + n)
+            session.run_round(t + 1 + n)
             n += 1
             if time.perf_counter() - w0 >= seconds:
                 break
-        jax.block_until_ready(bank_tree(srv))
+        jax.block_until_ready(session.state())
         w1 = time.perf_counter()
     probe.counting = False
     if traced:
         jax.profiler.stop_trace()
     window_compiles = probe.compiles
     mem_peak = memory_peak()
-    probe.close()
     rounds, spans = probe.rounds, probe.spans
-    captured = [probe.captured[r] for r in range(1, n_compare + 1)]
-    timed = {probe.programs[r["round"]] for r in rounds}
-    untimed = sum(probe.programs[r] not in timed
-                  for r in range(1, n_compare + 1))
-    del srv, probe
+    session.release()
     gc.collect()
 
     reduced = None
@@ -347,22 +313,17 @@ def run_cell(spec: Dict, seed: int, seconds: float, traced: bool,
         log(f"trace: reduced in {time.perf_counter() - t0:.1f} s")
 
     t0 = time.perf_counter()
-    numbers = judge(spec, seed, captured, inputs, params0, fed)
-    numbers["programs"] = float(untimed)
+    numbers = judge(spec, seed, session)
+    numbers["programs"] = stray_programs(
+        session.programs, list(range(1, n_compare + 1)),
+        [r["round"] for r in rounds])
     limits = spec["limits"]
     ok = correct.verdict({k: numbers.get(k, float("nan")) for k in limits},
                          limits)
     ref_s = time.perf_counter() - t0
 
-    part = config["partition"]
-    steps = fed["local_epochs"] * (part["n_train"] // config["batch_size"])
-    n_dev = fed["n_devices"]
-    fwd = flops.model_forward_flops(config["model"])
     for r in rounds:
-        r["samples"] = r["pairs"] * steps * config["batch_size"]
-        r["flops"] = fwd * (3 * r["samples"] + n_dev * (
-            r["val_rows"] * part["n_val"]
-            + (r["test_rows"] + r["extra_test_rows"]) * part["n_test"]))
+        r["samples"], r["flops"] = session.work(r)
     peaks = json.loads((BENCH / "peaks.json").read_text())
     ctx = RunContext(setup_s=setup_s, window_s=w1 - w0, rounds=rounds,
                      spans=spans, memory_peak_bytes=mem_peak,
@@ -379,8 +340,7 @@ def run_cell(spec: Dict, seed: int, seconds: float, traced: bool,
     log(f"window: {n} rounds in {w1 - w0:.3f} s of {seconds} s asked; "
         f"compiles in window {window_compiles}; warm-up compile events "
         f"{setup['warm_compiles']}")
-    log("rounds: " + " ".join(f"{r['round']}:B{r['pairs']}/L{r['val_rows']}"
-                              for r in rounds))
+    log("rounds: " + round_runs(rounds))
     log("longest rounds: " + json.dumps(longest_rounds(spans)))
     log(f"reference: {ref_s:.1f} s; correct: {ok}")
     log("readings: " + json.dumps(
@@ -402,6 +362,21 @@ def run_cell(spec: Dict, seed: int, seconds: float, traced: bool,
     out["compared"] = {k: {"value": numbers.get(k), "limit": limits[k]}
                        for k in limits}
     return out
+
+
+def round_runs(rounds: List[Dict]) -> str:
+    """The window's round records as runs of consecutive rounds that
+    differ in nothing but their number: ``6-149 pairs=10 live=1 ...``."""
+    runs: List[list] = []
+    for r in rounds:
+        rest = {k: v for k, v in r.items() if k != "round"}
+        if runs and runs[-1][1] == r["round"] - 1 and runs[-1][2] == rest:
+            runs[-1][1] = r["round"]
+        else:
+            runs.append([r["round"], r["round"], rest])
+    return "; ".join(f"{a}-{b} " + " ".join(f"{k}={v}" for k, v in
+                                            rest.items())
+                     for a, b, rest in runs)
 
 
 def longest_rounds(spans: List[tuple], k: int = 3) -> List[Dict]:
@@ -454,10 +429,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
-    spec = load_cell(args.workload)
     try:
-        out = run_cell(spec, args.seed, args.seconds, bool(args.trace))
-    except NoChip as e:
+        out = run_cell(load_cell(args.workload), args.seed, args.seconds,
+                       bool(args.trace))
+    except (NoChip, BadCell) as e:
         print(f"run.py: {e}", file=sys.stderr)
         return 1
     print(json.dumps(out))
